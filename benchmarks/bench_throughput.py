@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 
 from repro.core import collectives as coll
 from repro.core import ops

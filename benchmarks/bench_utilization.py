@@ -19,8 +19,6 @@ PKT = 1024  # words per packet for the costing
 
 def _cost(fn, *args):
     c = jax.jit(fn).lower(*args).compile().cost_analysis() or {}
-    if isinstance(c, (list, tuple)):   # older jax: one dict per program
-        c = c[0] if c else {}
     return c.get("flops", 0.0), c.get("bytes accessed", 0.0)
 
 

@@ -63,6 +63,8 @@ _ROW_RE = re.compile(r"^([\w/.+-]+),(-?[\d.]+),(.*)$")
 
 def run_sub(mod: str, devices: int, extra_env=None):
     env = dict(os.environ)
+    # CPU emulation: a child must never try to take an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + REPO
     if extra_env:
@@ -200,6 +202,7 @@ def run_comm_lint() -> dict:
         proc = subprocess.run(
             [sys.executable, os.path.join(REPO, "scripts", "comm_lint.py"),
              "--json", path],
+            env=dict(os.environ, JAX_PLATFORMS="cpu"),
             capture_output=True, text=True, timeout=900)
         sys.stdout.write(proc.stdout)
         if proc.returncode:

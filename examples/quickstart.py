@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 
 from repro.core import collectives, handlers as hd, ops
 from repro.core.address_space import GlobalAddressSpace

@@ -115,7 +115,7 @@ def _build_moe_dispatch():
 
     from repro.models.model import ModelConfig, build_model
     from repro.models.moe import MoEDims
-    from repro.runtime.jax_compat import make_mesh
+    from repro.runtime.topology import make_mesh
 
     mesh = make_mesh((2, 4), ("data", "model"))
     dims = MoEDims(n_experts=8, top_k=2, d_ff_expert=64, n_shared=1,

@@ -6,8 +6,9 @@ The grid (N x N) is row-partitioned over kernels.  Each iteration:
      neighbors' halo slots (Shoal Long puts — *not* send/recv pairs;
      boundary kernels simply aren't in the pattern),
   2. waits for its own halos' replies (wait_replies = GASNet quiet),
-  3. runs the von Neumann stencil over its band (optionally the Pallas
-     kernel from :mod:`repro.kernels.jacobi`).
+  3. runs the von Neumann stencil over its band: jnp, or with
+     ``use_pallas`` the Pallas kernel from :mod:`repro.kernels.jacobi`
+     (compiled; ``interpret=True`` runs its body on the CPU).
 
 Segment layout per kernel: [0, N) = top halo row, [N, 2N) = bottom halo.
 
@@ -38,13 +39,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 import numpy as np
 
 from repro.core import handlers as hd
 from repro.core import ops
 from repro.core.gascore import dataclasses_replace
 from repro.core.state import PgasState, ShoalContext
+from repro.kernels.jacobi import jacobi_band_step
 from repro.runtime import TCP
 from repro.runtime.topology import make_cpu_mesh
 
@@ -56,6 +58,7 @@ class JacobiApp:
     iters: int
     transport: object = TCP
     use_pallas: bool = False
+    interpret: bool = False   # Pallas interpret mode (CPU runs)
     piggyback: bool = True    # defer halo acks onto the next iteration's
                               # reverse-link data packet (acked transports)
 
@@ -132,11 +135,14 @@ class JacobiApp:
                               (me < self.kernels - 1).astype(jnp.int32))
         return st
 
-    def _stencil(self, block_pad: jnp.ndarray, kid) -> jnp.ndarray:
-        """block_pad: (rows+2, n) with halo rows attached.  (The Pallas
-        variant of this loop is benchmarked separately in
-        benchmarks/bench_utilization.py; on the CPU host the jnp form is
-        what XLA vectorizes best, mirroring the paper's SW/HW split.)"""
+    def _stencil(self, block: jnp.ndarray, top: jnp.ndarray,
+                 bot: jnp.ndarray, kid) -> jnp.ndarray:
+        """One stencil pass over this kernel's (rows, n) band, given the
+        halo rows above and below it."""
+        if self.use_pallas:
+            return jacobi_band_step(block, top, bot, kid * self.rows,
+                                    m_total=self.n, interpret=self.interpret)
+        block_pad = jnp.concatenate([top[None], block, bot[None]], axis=0)
         up = block_pad[:-2]
         down = block_pad[2:]
         mid = block_pad[1:-1]
@@ -159,8 +165,7 @@ class JacobiApp:
         # boundary kernels have no halo: use zero rows (masked anyway)
         top = jnp.where(kid > 0, top_halo, 0.0)
         bot = jnp.where(kid < self.kernels - 1, bot_halo, 0.0)
-        pad = jnp.concatenate([top[None], block, bot[None]], axis=0)
-        block = self._stencil(pad, kid)
+        block = self._stencil(block, top, bot, kid)
         st = ops.barrier(self.ctx, st)
         return st, block
 

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 
 from repro.core.state import PgasState, ShoalContext
 

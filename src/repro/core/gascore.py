@@ -514,9 +514,9 @@ def ingress_reliable_stack(ctx: ShoalContext, state: PgasState,
 
     state = dataclasses_replace(
         state, segment=_pad_segment(state.segment, packet_words))
-    (state, ack_hdr), _ = lax.scan(
-        body, (state, jnp.zeros((am.HDR_WORDS,), jnp.int32)),
-        (hdr_rows, pay_rows))
+    ack0 = hd.vary_like(jnp.zeros((am.HDR_WORDS,), jnp.int32),
+                        state, hdr_rows, pay_rows)
+    (state, ack_hdr), _ = lax.scan(body, (state, ack0), (hdr_rows, pay_rows))
     return dataclasses_replace(
         state, segment=state.segment[:ctx.segment_words]), ack_hdr
 

@@ -76,7 +76,27 @@ class HandlerTable:
             (lambda r, p, f=fn: f(r, p)) for _, fn in self._entries
         ]
         idx = jnp.clip(handler_id, 0, len(branches) - 1)
+        # Inside shard_map every branch must vary over the same manual
+        # axes: a NOP returns the region, a WRITE the payload.
+        region, payload = vary_like((region, payload), region, payload)
         return jax.lax.switch(idx, branches, region, payload)
+
+
+def vary_like(tree, *like):
+    """Mark every leaf of ``tree`` as varying over each manual mesh axis
+    that any leaf of ``like`` varies over (a no-op outside ``shard_map``).
+
+    ``lax.switch`` branches and ``lax.scan`` carries must agree on their
+    varying manual axes; values built from constants start invariant.
+    """
+    axes = frozenset().union(
+        *(jax.typeof(x).vma for x in jax.tree_util.tree_leaves(like)))
+
+    def cast(x):
+        missing = tuple(sorted(axes - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree_util.tree_map(cast, tree)
 
 
 DEFAULT_TABLE = HandlerTable()

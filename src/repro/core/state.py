@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 
 from repro.core import handlers as hd
 from repro.runtime.transport import Transport, TCP

@@ -40,7 +40,7 @@ def _unpack_kernel(pay_ref, seg_in_ref, seg_ref, *, addr, stride, blk_words,
                                              "nblocks", "interpret"))
 def am_pack_pallas(segment: jnp.ndarray, addr: int, *, stride: int,
                    blk_words: int, nblocks: int,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool = False) -> jnp.ndarray:
     S = segment.shape[0]
     return pl.pallas_call(
         functools.partial(_pack_kernel, addr=addr, stride=stride,
@@ -57,7 +57,7 @@ def am_pack_pallas(segment: jnp.ndarray, addr: int, *, stride: int,
                                              "nblocks", "interpret"))
 def am_unpack_pallas(segment: jnp.ndarray, payload: jnp.ndarray, addr: int, *,
                      stride: int, blk_words: int, nblocks: int,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool = False) -> jnp.ndarray:
     S = segment.shape[0]
     P = payload.shape[0]
     return pl.pallas_call(

@@ -80,7 +80,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            sm_scale: float | None = None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q,k,v: (BH, S, dh) -> (BH, S, dh).  S % block == 0 (wrapper pads)."""
     bh, s, dh = q.shape
     scale = float(sm_scale if sm_scale is not None else 1.0 / np.sqrt(dh))

@@ -10,7 +10,7 @@ from repro.kernels.attention.ref import attention_ref
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_k: int = 128, use_pallas: bool = True,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q,k,v: (BH, S, dh).  Pads S up to a block multiple (padded key rows
     are masked out by causality given padded queries are discarded)."""
     if not use_pallas:

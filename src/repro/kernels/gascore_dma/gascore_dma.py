@@ -60,7 +60,7 @@ def _ring_kernel(x_ref, o_ref, carry, inbox, send_sem, recv_sem, *,
 
 @functools.partial(jax.jit, static_argnames=("axis_name", "n", "interpret"))
 def ring_allreduce_dma_local(x, *, axis_name: str, n: int,
-                             interpret: bool = True):
+                             interpret: bool = False):
     """Per-device body (inside shard_map over ``axis_name``).
     x: (chunk,) local block -> (chunk,) sum over all n devices."""
     chunk = x.shape[0]

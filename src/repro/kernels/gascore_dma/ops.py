@@ -5,12 +5,12 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 
 from repro.kernels.gascore_dma.gascore_dma import ring_allreduce_dma_local
 
 
-def ring_allreduce_dma(mesh, axis_name: str, x, *, interpret: bool = True):
+def ring_allreduce_dma(mesh, axis_name: str, x, *, interpret: bool = False):
     """x: global (n*chunk,) array sharded over ``axis_name``; returns the
     all-reduced value with the same sharding (every shard = total sum of
     its position's blocks ... i.e. each device's block becomes the sum of

@@ -10,26 +10,48 @@ import jax.numpy as jnp
 from repro.kernels.jacobi.jacobi import jacobi_step_pallas
 from repro.kernels.jacobi.ref import jacobi_step_ref
 
+# Bytes of one (block_rows, N) band.  Four double-buffered bands plus
+# the kernel's band-sized temporaries must fit v5e's scoped VMEM; 4 MiB
+# bands (256 rows at N=4096 f32) do not, 1 MiB bands do.
+_BAND_BYTES = 1 << 20
 
-def _pick_block_rows(m: int, want: int = 256) -> int:
-    for b in (want, 128, 64, 32, 16, 8, 4, 2, 1):
-        if m % b == 0:
-            return b
-    return 1
+
+def _pick_block_rows(m: int, n: int, itemsize: int) -> int:
+    """Largest multiple of 8 dividing ``m`` whose band fits the budget."""
+    if m % 8:
+        raise ValueError(f"the Pallas Jacobi stencil needs a multiple of 8 "
+                         f"rows per band, got {m}")
+    b = max(8, min(m, _BAND_BYTES // (n * itemsize)) // 8 * 8)
+    while m % b:
+        b -= 8
+    return b
+
+
+def jacobi_band_step(band: jnp.ndarray, top: jnp.ndarray, bottom: jnp.ndarray,
+                     row0, *, m_total: int,
+                     interpret: bool = False) -> jnp.ndarray:
+    """One Pallas iteration over a row band of an (m_total, N) grid, given
+    the halo rows just above/below it and its first global row."""
+    m, n = band.shape
+    return jacobi_step_pallas(
+        band, top, bottom, row0, m_total=m_total,
+        block_rows=_pick_block_rows(m, n, band.dtype.itemsize),
+        interpret=interpret)
 
 
 def jacobi_step(x: jnp.ndarray, *, use_pallas: bool = True,
-                interpret: bool = True) -> jnp.ndarray:
-    """One iteration; pallas kernel or jnp oracle."""
+                interpret: bool = False) -> jnp.ndarray:
+    """One iteration over a whole grid; pallas kernel or jnp oracle."""
     if not use_pallas:
         return jacobi_step_ref(x)
-    return jacobi_step_pallas(x, block_rows=_pick_block_rows(x.shape[0]),
-                              interpret=interpret)
+    zero = jnp.zeros((x.shape[1],), x.dtype)
+    return jacobi_band_step(x, zero, zero, 0, m_total=x.shape[0],
+                            interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("iters", "use_pallas", "interpret"))
 def jacobi_run(x: jnp.ndarray, iters: int, *, use_pallas: bool = False,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool = False) -> jnp.ndarray:
     """``iters`` Jacobi iterations (lax.fori_loop over the step)."""
     def body(_, g):
         return jacobi_step(g, use_pallas=use_pallas, interpret=interpret)

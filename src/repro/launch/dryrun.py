@@ -99,8 +99,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     if save_hlo:
         with open(save_hlo, "w") as f:

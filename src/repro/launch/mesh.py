@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.runtime.jax_compat import make_mesh
+from repro.runtime.topology import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

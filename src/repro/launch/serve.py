@@ -12,7 +12,20 @@ import numpy as np
 
 from repro import configs
 from repro.models.model import build_model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.engine import Request, ServeEngine
+
+
+def build_engine(cfg, *, seed: int, lanes: int, slots: int) -> ServeEngine:
+    """Random weights from ``seed``, stored in the model's compute dtype."""
+    model = build_model(cfg)
+
+    @jax.jit
+    def init(key):
+        return jax.tree.map(lambda p: p.astype(cfg.dtype), model.init(key))
+
+    return ServeEngine(model, init(jax.random.PRNGKey(seed)), lanes=lanes,
+                       slots=slots)
 
 
 def main(argv=None):
@@ -29,9 +42,9 @@ def main(argv=None):
     cfg = configs.reduced(args.arch) if args.reduced else configs.full(args.arch)
     if cfg.frontend != "tokens":
         raise SystemExit("serving demo supports token-frontend archs")
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    engine = ServeEngine(model, params, lanes=args.lanes, slots=args.slots)
+    enable_compile_cache()
+    engine = build_engine(cfg, seed=args.seed, lanes=args.lanes,
+                          slots=args.slots)
 
     rng = np.random.default_rng(args.seed)
     reqs = [Request(rid=i,
@@ -45,8 +58,10 @@ def main(argv=None):
     toks = sum(len(r.out) for r in done)
     for r in done:
         print(f"req {r.rid}: prompt {list(r.prompt)} -> {r.out}")
+    dev = jax.devices()[0]
     print(f"[serve] {len(done)} requests, {toks} tokens in {dt:.2f}s "
-          f"({toks / dt:.1f} tok/s on CPU, {args.lanes} lanes)")
+          f"({toks / dt:.1f} tok/s on {dev.platform} {dev.device_kind}, "
+          f"{args.lanes} lanes)")
     return 0
 
 

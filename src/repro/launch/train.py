@@ -6,22 +6,12 @@
 Features exercised end-to-end: data pipeline state in the checkpoint,
 async checkpointing off the critical path, automatic restore-on-restart
 (re-running the same command resumes), retry-on-failure with bounded
-restarts, comm-backend selection, and the latency-hiding scheduler flags
-a real pod deployment would set.
+restarts and comm-backend selection.
 """
 
 import argparse
-import os
 import sys
 import time
-
-# compute/comm overlap: enable XLA's latency-hiding scheduler for
-# collectives (harmless on CPU; the production win on pods)
-os.environ.setdefault(
-    "XLA_FLAGS",
-    "--xla_tpu_enable_latency_hiding_scheduler=true"
-    if "tpu" in os.environ.get("JAX_PLATFORMS", "")
-    else os.environ.get("XLA_FLAGS", ""))
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +22,7 @@ from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.models.model import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.optim.schedule import warmup_cosine
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.training.elastic import FailureInjector
 from repro.training.train import Trainer, TrainerConfig
 
@@ -109,6 +100,7 @@ def main(argv=None):
                     help="inject failures at these steps (fault-tolerance demo)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     injector = FailureInjector(set(args.fail_at)) if args.fail_at else None
     for attempt in range(args.max_restarts + 1):
         try:

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 
 from repro.models import attention as attn
 from repro.models import blocks as bl
@@ -389,17 +389,6 @@ class Model:
     def _constrain(self, x, spec):
         if self.mesh is None:
             return x
-        # Inside a fully-manual shard_map region (old-jax compat path)
-        # sharding hints over the manual axes are illegal and
-        # meaningless — the data is already placed.  Skip them there.
-        from repro.runtime.jax_compat import bound_axis_names
-        bound = bound_axis_names()
-        if bound:
-            def touches_bound(a):
-                axes = a if isinstance(a, tuple) else (a,)
-                return any(x in bound for x in axes)
-            if any(a is not None and touches_bound(a) for a in spec):
-                return x
         return jax.lax.with_sharding_constraint(
             x, jax.sharding.NamedSharding(self.mesh, spec))
 

@@ -27,7 +27,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 
 
 def _block_attend(q, k, v, q_pos, k_pos, scale):

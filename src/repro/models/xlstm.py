@@ -76,7 +76,10 @@ def _chunk_mlstm(q, k, v, logf, logi, chunk: int, init=None):
     ws_ = (csum_f - ic)[:, :, None, :, :]             # (B,nc,1,T,nh)
     logw = wq_ - ws_                                  # (B,nc,T,T,nh)
     mask = jnp.tril(jnp.ones((chunk, chunk), bool))
-    w = jnp.where(mask[None, None, :, :, None], jnp.exp(logw), 0.0)
+    # mask BEFORE the exp: above the diagonal logw grows with the chunk
+    # length and exp overflows to inf, whose gradient through a where
+    # is 0 * inf = NaN
+    w = jnp.exp(jnp.where(mask[None, None, :, :, None], logw, -jnp.inf))
     scores = jnp.einsum("bcthd,bcshd->bctsh", qc, kc) / jnp.sqrt(dh)
     h_intra = jnp.einsum("bctsh,bctsh,bcshd->bcthd",
                          scores.astype(jnp.float32), w, vc.astype(jnp.float32))
@@ -224,7 +227,8 @@ def init_slstm(key, d, nh, mlp_pf: float = 4.0 / 3.0):
     return {
         "ln": jnp.ones((d,), jnp.float32),
         "w": bl.dense_init(ks[0], (d, 4 * d)),            # i,f,z,o pre-acts
-        "r": bl.dense_init(ks[1], (nh, dh, 4 * dh)) * 0.5,  # block-diag recurrent
+        # block-diag recurrent; fan-in is dh (axis 1), not the head count
+        "r": bl.dense_init(ks[1], (nh, dh, 4 * dh), in_axis=1) * 0.5,
         "gn": jnp.ones((d,), jnp.float32),
         "ln2": jnp.ones((d,), jnp.float32),
         "wg": bl.dense_init(ks[2], (d, f)),

@@ -14,9 +14,7 @@ import math
 from typing import Sequence
 
 import jax
-import numpy as np
-
-from repro.runtime.jax_compat import make_mesh as _compat_make_mesh
+from jax.sharding import AxisType
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,7 +66,8 @@ class ClusterSpec:
 
 def make_mesh(shape: Sequence[int], names: Sequence[str]) -> jax.sharding.Mesh:
     """Build a mesh with explicit Auto axis types (silences 0.9 deprecation)."""
-    return _compat_make_mesh(shape, names)
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_cpu_mesh(n: int | None = None, names: tuple[str, ...] = ("kernel",)):

@@ -36,7 +36,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.address_space import GlobalAddressSpace
 from repro.core.state import ShoalContext
 from repro.launch.mesh import ServingSlices, make_serving_mesh
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 from repro.runtime.transport import TCP
 from repro.serving.engine import Request, ServeEngine, lane_slice, reset_lane
 from repro.serving.kv_space import MIGRATE_TOKEN, KvSegmentSpace

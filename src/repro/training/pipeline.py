@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(mesh, axis: str, stage_fn, stage_params, mbs):

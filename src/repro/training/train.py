@@ -12,7 +12,7 @@ Two backends, both producing the same math (tested against each other):
   all-reduce (:func:`repro.core.collectives.ring_all_reduce`) — i.e. the
   one-sided Long-put-with-ADD datapath.  Optional int8 error-feedback
   compression on the sync.  Requires replicated-over-DP params (no
-  FSDP) — documented in DESIGN.md.
+  FSDP): every rank applies the synced gradient to a full copy.
 
 Also here: gradient accumulation (microbatching), straggler-quorum DP
 (see :mod:`repro.training.elastic`), and metrics.
@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.runtime.jax_compat import shard_map
+from jax import shard_map
 
 from repro.core import collectives as coll
 from repro.models.model import Model
@@ -163,8 +163,8 @@ class Trainer:
         mesh = self.mesh
         assert mesh is not None, "shoal backend needs a mesh"
         assert not self.model.cfg.fsdp, (
-            "shoal DP backend needs replicated-over-DP params (no FSDP); "
-            "see DESIGN.md Sec. 4")
+            "shoal DP backend needs replicated-over-DP params (no FSDP): "
+            "its ring all-reduces whole gradient leaves")
         dp = self.dp_axes
         n_dp = 1
         for a in dp:
@@ -187,8 +187,8 @@ class Trainer:
 
             def one(qs):
                 q, s = qs
-                # sum int8 payloads in int32 (4x fewer wire bytes than f32
-                # on the pod/DP axis), scales reduced alongside
+                # int8 payloads are summed in int32, so the ring ships as
+                # many bytes as f32; scales are reduced alongside
                 red = coll.ring_all_reduce(q.astype(jnp.int32), dp, n_dp)
                 smax = coll.ring_all_reduce(s[None], dp, n_dp)[0] / n_dp
                 return (red.astype(jnp.float32) * smax / n_dp)

@@ -14,8 +14,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_subprocess_checks(script: str, n_devices: int = 8, timeout=900):
-    """Run a check script in a fresh process with N host devices."""
+    """Run a check script in a fresh process with N host devices (on the
+    CPU: a child must never try to take an accelerator from its parent)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(

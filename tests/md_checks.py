@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.runtime.jax_compat import make_mesh as compat_make_mesh, shard_map
+from jax import shard_map
 
 from repro.core import collectives as coll
 from repro.core import handlers as hd
@@ -20,6 +20,7 @@ from repro.core import humboldt, ops
 from repro.core.address_space import GlobalAddressSpace
 from repro.core.state import ShoalContext
 from repro.runtime import TCP, UDP, make_cpu_mesh
+from repro.runtime.topology import make_mesh
 
 N = 8
 RING = [(i, (i + 1) % N) for i in range(N)]
@@ -456,7 +457,7 @@ def test_trainer_backends_agree():
     from repro.data.pipeline import DataConfig, TokenPipeline
 
     mesh = make_cpu_mesh(N, ("kernel",))
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
                       n_heads=4, n_kv_heads=2, d_ff=128, vocab=256,
                       dtype=jnp.float32)
@@ -507,7 +508,7 @@ def test_trainer_backends_agree():
 def test_elastic_reshard():
     check("checkpoint save on 8-way mesh, restore on 4-way mesh")
     from repro.checkpoint import CheckpointManager
-    mesh8 = compat_make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     x = jnp.arange(64.0).reshape(8, 8)
     xs = jax.device_put(x, NamedSharding(mesh8, P("data", None)))
     with tempfile.TemporaryDirectory() as d:
@@ -527,7 +528,7 @@ def test_ring_attention_exact():
     check("ring attention (seq-parallel, one-sided-put KV rotation)")
     from repro.models.ring_attention import ring_attention
     from repro.models.attention import _attend
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     B, S, K, G, dh = 2, 64, 2, 3, 16
     q = jnp.asarray(rng.standard_normal((B, S, K, G, dh)), jnp.float32)
@@ -545,7 +546,7 @@ def test_seq_shard_model_exact():
     check("seq_shard (ring) model forward+grad vs baseline")
     import dataclasses
     from repro.models.model import ModelConfig, build_model
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
                       n_heads=4, n_kv_heads=2, d_ff=128, vocab=128,
                       dtype=jnp.float32, tp=False, seq_shard=True)
@@ -570,7 +571,7 @@ def test_moe_dispatch_variants_exact():
     import dataclasses
     from repro.models.model import ModelConfig, build_model
     from repro.models.moe import MoEDims
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     base = MoEDims(n_experts=8, top_k=2, d_ff_expert=64, n_shared=1,
                    capacity_factor=16.0)
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 128, (4, 32)),
@@ -595,11 +596,11 @@ def test_moe_dispatch_variants_exact():
 def test_gascore_rdma_ring():
     check("Pallas RDMA ring all-reduce (the literal GAScore) vs psum")
     from repro.kernels.gascore_dma import ring_allreduce_dma
-    mesh = compat_make_mesh((8,), ("x",))
+    mesh = make_mesh((8,), ("x",))
     for chunk, dt, tol in [(128, jnp.float32, 1e-5), (64, jnp.bfloat16, 5e-2)]:
         x = jnp.asarray(np.random.default_rng(0).standard_normal(8 * chunk),
                         dt)
-        got = np.asarray(ring_allreduce_dma(mesh, "x", x),
+        got = np.asarray(ring_allreduce_dma(mesh, "x", x, interpret=True),
                          np.float32).reshape(8, chunk)
         want = np.asarray(x, np.float32).reshape(8, chunk).sum(0)
         for r in range(8):
@@ -609,7 +610,7 @@ def test_gascore_rdma_ring():
 def test_pipeline_parallel():
     check("2-stage pipeline over the pod axis (Medium-AM handoffs)")
     from repro.training.pipeline import pipeline_apply, split_stages
-    mesh = compat_make_mesh((2, 4), ("pod", "chip"))
+    mesh = make_mesh((2, 4), ("pod", "chip"))
     rng = np.random.default_rng(0)
     L, d = 4, 16
     w = jnp.asarray(rng.standard_normal((L, d, d)) * 0.3, jnp.float32)

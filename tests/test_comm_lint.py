@@ -23,7 +23,7 @@ admissible arrival reorder:
 
 import random
 
-from _hypothesis_compat import given, settings, strategies
+from hypothesis import given, settings, strategies
 from conftest import run_subprocess_checks
 
 

@@ -14,6 +14,7 @@ def test_dryrun_one_cell(tmp_path):
     out = tmp_path / "cell.jsonl"
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"   # placeholder host devices only
     env.pop("XLA_FLAGS", None)   # dryrun sets its own device count
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",

@@ -4,7 +4,7 @@ GAScore stages are pure functions over headers/payloads/state)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import am, gascore as gc, handlers as hd
 from repro.core.state import PgasState, ShoalContext

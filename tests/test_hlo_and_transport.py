@@ -1,7 +1,7 @@
 """HLO collective parser unit tests + the analytic transport model."""
 
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.launch.hlo_analysis import parse_collectives, split_computations
 from repro.runtime.router import Router

@@ -65,6 +65,36 @@ def test_chunk_mlstm_matches_sequential():
                                rtol=2e-2, atol=2e-2)
 
 
+def test_chunk_mlstm_grads_finite_at_long_chunks():
+    """Above the diagonal the decay exponent reaches hundreds at chunk
+    256 (the xlstm-350m setting); the masked weights must not overflow
+    into the backward pass."""
+    B, S, nh, dh = 1, 256, 2, 8
+    q, k, v = (jnp.asarray(RNG.standard_normal((B, S, nh, dh)), jnp.float32)
+               for _ in range(3))
+    logf = jnp.asarray(np.log(RNG.uniform(0.4, 0.6, (B, S, nh))), jnp.float32)
+    logi = jnp.asarray(RNG.standard_normal((B, S, nh)) * 0.5, jnp.float32)
+
+    def loss(q, logf):
+        return jnp.sum(_chunk_mlstm(q, k, v, logf, logi, chunk=S)[0])
+
+    grads = jax.grad(loss, argnums=(0, 1))(q, logf)
+    assert all(np.isfinite(np.asarray(g)).all() for g in grads)
+
+
+def test_slstm_grads_bounded_at_full_width():
+    """The recurrent weights are scaled by the per-head fan-in dh; scaled
+    by the head count instead, the backward pass through a long scan at
+    the xlstm-350m width (d 1024, 4 heads) grows to ~1e18."""
+    from repro.models.xlstm import init_slstm, slstm_block
+    d, nh, S = 1024, 4, 256
+    p = init_slstm(jax.random.PRNGKey(0), d, nh)
+    x = jnp.asarray(RNG.standard_normal((1, S, d)), jnp.float32)
+    g = jax.grad(lambda x: jnp.sum(slstm_block(p, x, nh=nh)[0][:, -1]))(x)
+    assert np.isfinite(np.asarray(g)).all()
+    assert float(jnp.max(jnp.abs(g))) < 1e3
+
+
 def test_chunk_mlstm_final_state_continues():
     """Chunked prefill final state == sequential recurrence state, so a
     decode continuation is consistent."""

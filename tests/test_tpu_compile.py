@@ -1,0 +1,105 @@
+"""Compile the main path's programs for a described TPU v5e (no chip).
+
+The TPU compiler refuses what interpret mode accepts: unaligned blocks,
+kernels that overflow VMEM, programs that do not partition.  These
+compiles guard the paths ``chip_smoke.py`` runs on the chip.  The
+topology is described inside a fixture: only one process at a time may
+load the TPU library, so nothing here touches it at import.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from repro.core.state import PgasState
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def kernel_mesh(topo):
+    return lambda n, names=("kernel",): Mesh(np.array(topo.devices[:n]),
+                                             names)
+
+
+def _state_shapes(ctx):
+    """Global PgasState shapes (leading kernel dim) sharded over ctx.mesh."""
+    shd = NamedSharding(ctx.mesh, P(ctx.axes))
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((ctx.num_kernels,) + x.shape, x.dtype,
+                                       sharding=shd),
+        jax.eval_shape(lambda: PgasState.make(ctx.segment_words)))
+
+
+def test_jacobi_stencil_4096_compiles(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.kernels.jacobi import jacobi_step
+    x = jax.ShapeDtypeStruct((4096, 4096), jnp.float32,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+    hlo = jax.jit(jacobi_step).lower(x).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_jacobi_app_4_kernels_compiles(kernel_mesh, monkeypatch, use_pallas):
+    import repro.apps.jacobi as jacobi_app
+    monkeypatch.setattr(jacobi_app, "make_cpu_mesh", kernel_mesh)
+    app = jacobi_app.JacobiApp(n=4096, kernels=4, iters=2,
+                               use_pallas=use_pallas)
+    blocks = jax.ShapeDtypeStruct(
+        (4, app.rows, app.n), jnp.float32,
+        sharding=NamedSharding(app.mesh, P(("kernel",))))
+    hlo = app.build().lower(_state_shapes(app.ctx),
+                            blocks).compile().as_text()
+    assert "collective-permute" in hlo
+    assert ("tpu_custom_call" in hlo) == use_pallas
+
+
+def test_local_put_long_and_mixed_mailbox_compile(kernel_mesh):
+    from repro.actors import Mailbox
+    from repro.core import handlers as hd
+    from repro.core import ops
+    from repro.core.state import ShoalContext
+    from repro.runtime import TCP
+
+    local = [(0, 0)]
+    ctx = ShoalContext(mesh=kernel_mesh(1), axes=("kernel",), transport=TCP,
+                       segment_words=1 << 15)
+    n_long = 8 * TCP.max_packet_words + 100
+    rng = np.random.default_rng(0)
+
+    def prog(st, payload):
+        st = jax.tree.map(lambda x: x[0], st)
+        st = ops.put_long(ctx, st, payload, local, 0, token=1)
+        st = ops.wait_replies(ctx, st, token=1, n=1)
+        mb = Mailbox(ctx, local, msg_words=4, watermark=512, token=5)
+        for i in range(256):
+            if i % 4 == 3:
+                st = mb.send_signal(st, arg=1, token=7)
+            else:
+                st = mb.send(st, rng.standard_normal(1 + i % 4),
+                             dst_addr=20000 + 4 * i,
+                             handler=hd.H_ADD if i % 2 else hd.H_WRITE)
+        st = mb.flush(st)
+        return jax.tree.map(lambda x: x[None], st)
+
+    spec = P(ctx.axes)
+    fn = jax.jit(jax.shard_map(prog, mesh=ctx.mesh, in_specs=(spec, P()),
+                               out_specs=spec))
+    payload = jax.ShapeDtypeStruct((n_long,), jnp.float32,
+                                   sharding=NamedSharding(ctx.mesh, P()))
+    fn.lower(_state_shapes(ctx), payload).compile()

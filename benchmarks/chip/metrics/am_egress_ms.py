@@ -1,0 +1,12 @@
+"""Device self time per iteration charged to the ``egress`` layer scope
+(packet build: header encode, piggyback lane, masking, payload egress),
+mean over the cell's chips, in ms.  Ops are charged to layers through
+the executed module's instruction metadata (``layers.py``); nothing is
+read where the program has no layer scopes or the map leaves over 1% of
+a chip's busy time unmapped."""
+
+import layers
+
+
+def read(run):
+    return layers.read(run, "egress")

@@ -16,6 +16,27 @@ jaxpr's equations and maps them back to events by tag.  The same tags
 show up as ``op_name`` metadata in compiled HLO, which is how a budget
 finding in pass 2 can name the op that emitted the collective.
 
+Layer scopes: :func:`layer` wraps the equations of one layer of the
+comm path in a ``layer.<name>`` named scope, which also lands in the
+compiled HLO's ``op_name`` metadata, so an instruction -- and through
+:func:`repro.launch.hlo_analysis.op_layers` a profiler's device event --
+can be charged to its layer.  The names are fixed (:data:`LAYERS`):
+
+* ``compute``: the application's own compute step (for Jacobi, the
+  stencil: its halo-row selects, the Pallas kernel's shifted views and
+  call);
+* ``egress``: packet build (header encode, piggyback lane, NOP masking,
+  the GAScore's payload egress, the fused packet);
+* ``wire``: the collectives that cross the links, and nothing else;
+* ``ingress``: the GAScore's ingress of data, replies and ack lanes
+  (the fused packet's unpacking included);
+* ``sync``: barrier, ``wait_replies`` and the deferred-ack drain.
+
+Scopes nest and the innermost wins: a barrier's ``psum`` is ``wire``
+inside ``sync``.  They are metadata only, so they change no instruction
+and no fusion, and they use a ``layer.`` prefix so that
+:func:`recover_tags` counts the ``shoal.*`` tags alone.
+
 Traced (non-concrete) operands degrade conservatively: an interval
 whose start is unknown is recorded with ``start=None`` and treated by
 the rules as potentially overlapping everything in its segment.
@@ -203,6 +224,18 @@ def emit(op: str, pattern, **kw) -> str:
 def scope(tag: str):
     """Named scope wrapping an op's equations with its event tag."""
     return jax.named_scope(tag)
+
+
+LAYERS = ("compute", "egress", "wire", "ingress", "sync")
+LAYER_PREFIX = "layer."
+
+
+def layer(name: str):
+    """Named scope ``layer.<name>`` charging the equations inside it to
+    one of the :data:`LAYERS`."""
+    if name not in LAYERS:
+        raise ValueError(f"unknown layer {name!r}; one of {LAYERS}")
+    return jax.named_scope(LAYER_PREFIX + name)
 
 
 # --------------------------------------------------------------------------

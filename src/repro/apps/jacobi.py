@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax import shard_map
 import numpy as np
 
+from repro.analysis.trace import layer
 from repro.core import handlers as hd
 from repro.core import ops
 from repro.core.gascore import dataclasses_replace
@@ -160,12 +161,13 @@ class JacobiApp:
         n = self.n
         kid = self.ctx.my_id()
         st = self._halo_exchange(st, block, it)
-        top_halo = st.segment[:n]
-        bot_halo = st.segment[n:2 * n]
-        # boundary kernels have no halo: use zero rows (masked anyway)
-        top = jnp.where(kid > 0, top_halo, 0.0)
-        bot = jnp.where(kid < self.kernels - 1, bot_halo, 0.0)
-        block = self._stencil(block, top, bot, kid)
+        with layer("compute"):
+            top_halo = st.segment[:n]
+            bot_halo = st.segment[n:2 * n]
+            # boundary kernels have no halo: use zero rows (masked anyway)
+            top = jnp.where(kid > 0, top_halo, 0.0)
+            bot = jnp.where(kid < self.kernels - 1, bot_halo, 0.0)
+            block = self._stencil(block, top, bot, kid)
         st = ops.barrier(self.ctx, st)
         return st, block
 

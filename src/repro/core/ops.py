@@ -97,18 +97,23 @@ def _exchange(ctx: ShoalContext, pattern: Pattern, hdr: jnp.ndarray,
     if not remote:
         return (hdr, extra, payload) if extra is not None else (hdr, payload)
     if payload is None and extra is None:
-        return lax.ppermute(hdr, ctx.axes, pattern), None
+        with _lint.layer("wire"):
+            return lax.ppermute(hdr, ctx.axes, pattern), None
     if payload is not None and not am.wire_dtype_ok(payload.dtype):
-        hdr_r = lax.ppermute(hdr, ctx.axes, pattern)
-        pay_r = lax.ppermute(payload, ctx.axes, pattern)
-        if extra is None:
-            return hdr_r, pay_r
-        return hdr_r, lax.ppermute(extra, ctx.axes, pattern), pay_r
+        with _lint.layer("wire"):
+            hdr_r = lax.ppermute(hdr, ctx.axes, pattern)
+            pay_r = lax.ppermute(payload, ctx.axes, pattern)
+            if extra is None:
+                return hdr_r, pay_r
+            return hdr_r, lax.ppermute(extra, ctx.axes, pattern), pay_r
     n_extra = 0 if extra is None else extra.shape[-1]
     dtype = jnp.int32 if payload is None else payload.dtype
-    pkt = am.pack_packet(hdr, payload, extra)
-    pkt_r = lax.ppermute(pkt, ctx.axes, pattern)
-    out = am.unpack_packet(pkt_r, dtype, n_extra)
+    with _lint.layer("egress"):
+        pkt = am.pack_packet(hdr, payload, extra)
+    with _lint.layer("wire"):
+        pkt_r = lax.ppermute(pkt, ctx.axes, pattern)
+    with _lint.layer("ingress"):
+        out = am.unpack_packet(pkt_r, dtype, n_extra)
     if payload is None and extra is not None:
         return out[0], out[1], None
     return out
@@ -138,9 +143,11 @@ def _deliver_reply(ctx: ShoalContext, state: PgasState, pattern: Pattern,
     if reply_via is not None:
         reply_via.note(pattern, token)
         return state
-    rep = gc.auto_reply(hdr_at_dst)
+    with _lint.layer("egress"):
+        rep = gc.auto_reply(hdr_at_dst)
     rep_back, _ = _exchange(ctx, _reverse(pattern), rep, None)
-    return gc.ingress_reply(state, am.decode(rep_back))
+    with _lint.layer("ingress"):
+        return gc.ingress_reply(state, am.decode(rep_back))
 
 
 def _segments(nwords: int, limit: int):
@@ -278,20 +285,24 @@ def _lossy_exchange(ctx: ShoalContext, state: PgasState, pattern: Pattern,
     ``(state, hdr_rows, pay_rows)`` where the stacks are ``(2 * nseg,
     ...)`` with duplicate deliveries materialised in the second half.
     """
-    pkt = am.seal_packet(pkt)
+    with _lint.layer("egress"):
+        pkt = am.seal_packet(pkt)
     remote = [(s, d) for (s, d) in pattern if s != d]
-    pkt_r = lax.ppermute(pkt, ctx.axes, pattern) if remote else pkt
+    with _lint.layer("wire"):
+        pkt_r = lax.ppermute(pkt, ctx.axes, pattern) if remote else pkt
+    # the fault emulator stands in for the link: no layer of its own
     drop, dup, corrupt = _lossy_recv_probs(ctx, pattern)
     key = flt.fault_key(ctx.transport.faults, ctx.my_id(), token, epoch,
                         rnd, direction)
     delivered = flt.deliver(pkt_r, key, drop, dup, corrupt)
-    ok = am.packet_crc_ok(delivered)
-    state = gc.dataclasses_replace(
-        state, error=state.error | jnp.where(jnp.any(~ok), ERR_CRC, 0)
-        .astype(jnp.int32))
-    delivered = jnp.where(ok[:, None], delivered, 0)
-    hdr_rows = delivered[:, :am.HDR_WORDS]
-    pay_rows = am.from_wire(delivered[:, am.HDR_WORDS:], dtype)
+    with _lint.layer("ingress"):
+        ok = am.packet_crc_ok(delivered)
+        state = gc.dataclasses_replace(
+            state, error=state.error | jnp.where(jnp.any(~ok), ERR_CRC, 0)
+            .astype(jnp.int32))
+        delivered = jnp.where(ok[:, None], delivered, 0)
+        hdr_rows = delivered[:, :am.HDR_WORDS]
+        pay_rows = am.from_wire(delivered[:, am.HDR_WORDS:], dtype)
     return state, hdr_rows, pay_rows
 
 
@@ -322,8 +333,9 @@ def _put_long_reliable(ctx: ShoalContext, state: PgasState, pattern: Pattern,
     state = gc.dataclasses_replace(
         state, send_epoch=state.send_epoch.at[tok_c].add(
             sender.astype(jnp.int32)))
-    hdrs = hdrs.at[:, _I_EPOCH].set(
-        jnp.where(hdrs[:, _I_TYPE] != 0, epoch, 0))
+    with _lint.layer("egress"):
+        hdrs = hdrs.at[:, _I_EPOCH].set(
+            jnp.where(hdrs[:, _I_TYPE] != 0, epoch, 0))
     attempts = 1 + (ctx.transport.max_retries if acked else 0)
     pending = sender
     # tx under loss counts FULL wire cost (headers + payload per data
@@ -334,15 +346,18 @@ def _put_long_reliable(ctx: ShoalContext, state: PgasState, pattern: Pattern,
             state = gc.dataclasses_replace(
                 state, retransmits=state.retransmits
                 + pending.astype(jnp.int32))
-        rows = jnp.where(pending, hdrs, 0)
-        pay = jnp.where(pending, buf, jnp.zeros_like(buf))
-        state = gc.dataclasses_replace(
-            state, tx_words=state.tx_words + jnp.where(pending, wire, 0))
+        with _lint.layer("egress"):
+            rows = jnp.where(pending, hdrs, 0)
+            pay = jnp.where(pending, buf, jnp.zeros_like(buf))
+            state = gc.dataclasses_replace(
+                state, tx_words=state.tx_words + jnp.where(pending, wire, 0))
+            pkt = am.pack_packet(rows, pay)
         state, hdr_r, pay_r = _lossy_exchange(
-            ctx, state, pattern, am.pack_packet(rows, pay), buf.dtype,
+            ctx, state, pattern, pkt, buf.dtype,
             token=tok_c, epoch=epoch, rnd=rnd, direction=flt.DIR_DATA)
-        state, ack_hdr = gc.ingress_reliable_stack(ctx, state, hdr_r, pay_r,
-                                                   W, dedup=dedup)
+        with _lint.layer("ingress"):
+            state, ack_hdr = gc.ingress_reliable_stack(ctx, state, hdr_r,
+                                                       pay_r, W, dedup=dedup)
         if not acked:
             return state
         state = gc.dataclasses_replace(
@@ -351,10 +366,11 @@ def _put_long_reliable(ctx: ShoalContext, state: PgasState, pattern: Pattern,
         state, rep_r, _ = _lossy_exchange(
             ctx, state, _reverse(pattern), ack_hdr[None, :], jnp.int32,
             token=tok_c, epoch=epoch, rnd=rnd, direction=flt.DIR_REPLY)
-        t_col = rep_r[:, _I_TYPE]
-        got = jnp.any(((t_col & am._CLASS_MASK) == am.SHORT)
-                      & ((t_col & am.FLAG_REPLY) != 0)
-                      & (rep_r[:, _I_TOKEN] == tok_c))
+        with _lint.layer("ingress"):
+            t_col = rep_r[:, _I_TYPE]
+            got = jnp.any(((t_col & am._CLASS_MASK) == am.SHORT)
+                          & ((t_col & am.FLAG_REPLY) != 0)
+                          & (rep_r[:, _I_TOKEN] == tok_c))
         pending = pending & ~got
     delivered = sender & ~pending
     return gc.dataclasses_replace(
@@ -387,13 +403,16 @@ def put_short(ctx: ShoalContext, state: PgasState, pattern: Pattern, *,
         asynchronous=asynchronous, deferred_reply=reply_via is not None,
         credit_grants=grants, handler=h_s, segment_words=ctx.segment_words)
     with _lint.scope(tag):
-        t = am.make_type(am.SHORT, asynchronous=asynchronous)
-        hdr = am.encode(type=t, src=ctx.my_id(), dst=_dst_of(ctx, pattern),
-                        handler=handler, token=token, dst_addr=arg)
-        hdr = _mask_nonparticipants(ctx, pattern, hdr)
+        with _lint.layer("egress"):
+            t = am.make_type(am.SHORT, asynchronous=asynchronous)
+            hdr = am.encode(type=t, src=ctx.my_id(),
+                            dst=_dst_of(ctx, pattern), handler=handler,
+                            token=token, dst_addr=arg)
+            hdr = _mask_nonparticipants(ctx, pattern, hdr)
         hdr_r, _ = _exchange(ctx, pattern, hdr, None)
-        h = am.decode(hdr_r)
-        state = gc.ingress_short(ctx, state, h)
+        with _lint.layer("ingress"):
+            h = am.decode(hdr_r)
+            state = gc.ingress_short(ctx, state, h)
         return _deliver_reply(ctx, state, pattern, h,
                               asynchronous=asynchronous, token=token,
                               reply_via=reply_via)
@@ -432,21 +451,24 @@ def put_medium(ctx: ShoalContext, state: PgasState, payload: jnp.ndarray | None,
         nseg, W = len(segs), segs[0][1]
         offs = jnp.asarray([o for o, _ in segs], jnp.int32)
         ws = jnp.asarray([w for _, w in segs], jnp.int32)
-        hdrs = am.encode_batch(
-            nseg,
-            type=_seg_types(am.MEDIUM, nseg, asynchronous=asynchronous,
-                            fifo=fifo),
-            src=ctx.my_id(), dst=_dst_of(ctx, pattern), nwords=ws,
-            handler=handler, token=token,
-            src_addr=0 if fifo else from_segment_addr + offs, seq=offs)
-        hdrs = _mask_nonparticipants(ctx, pattern, hdrs)
-        buf = gc.egress_batch(ctx, state, hdrs, payload if fifo else None, W)
-        state = gc.dataclasses_replace(
-            state, tx_words=state.tx_words +
-            jnp.where(_is_sender(ctx, pattern),
-                      am.wire_words(state.segment.dtype, nwords), 0))
+        with _lint.layer("egress"):
+            hdrs = am.encode_batch(
+                nseg,
+                type=_seg_types(am.MEDIUM, nseg, asynchronous=asynchronous,
+                                fifo=fifo),
+                src=ctx.my_id(), dst=_dst_of(ctx, pattern), nwords=ws,
+                handler=handler, token=token,
+                src_addr=0 if fifo else from_segment_addr + offs, seq=offs)
+            hdrs = _mask_nonparticipants(ctx, pattern, hdrs)
+            buf = gc.egress_batch(ctx, state, hdrs,
+                                  payload if fifo else None, W)
+            state = gc.dataclasses_replace(
+                state, tx_words=state.tx_words +
+                jnp.where(_is_sender(ctx, pattern),
+                          am.wire_words(state.segment.dtype, nwords), 0))
         hdr_r, pay_r = _exchange(ctx, pattern, hdrs, buf)
-        state, delivered = gc.ingress_medium_batch(state, hdr_r, pay_r, W)
+        with _lint.layer("ingress"):
+            state, delivered = gc.ingress_medium_batch(state, hdr_r, pay_r, W)
         state = _deliver_reply(ctx, state, pattern, am.decode(hdr_r[-1]),
                                asynchronous=asynchronous, token=token,
                                reply_via=reply_via)
@@ -526,19 +548,21 @@ def put_long(ctx: ShoalContext, state: PgasState, payload: jnp.ndarray | None,
                 "split the message")
         offs = jnp.asarray([o for o, _ in segs], jnp.int32)
         ws = jnp.asarray([w for _, w in segs], jnp.int32)
-        hdrs = am.encode_batch(
-            nseg,
-            type=_seg_types(am.LONG, nseg, asynchronous=asynchronous,
-                            defer_ack=defer_ack, fifo=fifo),
-            src=ctx.my_id(), dst=_dst_of(ctx, pattern), nwords=ws,
-            dst_addr=dst_addr + offs,
-            src_addr=0 if fifo else from_segment_addr + offs,
-            handler=handler, token=token, seq=offs)
-        if piggyback_token is not None:
-            state, hdrs = _attach_piggyback(ctx, state, pattern, hdrs,
-                                            piggyback_token)
-        hdrs = _mask_nonparticipants(ctx, pattern, hdrs)
-        buf = gc.egress_batch(ctx, state, hdrs, payload if fifo else None, W)
+        with _lint.layer("egress"):
+            hdrs = am.encode_batch(
+                nseg,
+                type=_seg_types(am.LONG, nseg, asynchronous=asynchronous,
+                                defer_ack=defer_ack, fifo=fifo),
+                src=ctx.my_id(), dst=_dst_of(ctx, pattern), nwords=ws,
+                dst_addr=dst_addr + offs,
+                src_addr=0 if fifo else from_segment_addr + offs,
+                handler=handler, token=token, seq=offs)
+            if piggyback_token is not None:
+                state, hdrs = _attach_piggyback(ctx, state, pattern, hdrs,
+                                                piggyback_token)
+            hdrs = _mask_nonparticipants(ctx, pattern, hdrs)
+            buf = gc.egress_batch(ctx, state, hdrs,
+                                  payload if fifo else None, W)
         if lossy:
             if not am.wire_dtype_ok(buf.dtype):
                 raise NotImplementedError(
@@ -548,15 +572,17 @@ def put_long(ctx: ShoalContext, state: PgasState, payload: jnp.ndarray | None,
             return _put_long_reliable(ctx, state, pattern, hdrs, buf, W,
                                       nwords, token, acked=acked,
                                       dedup=dedup)
-        state = gc.dataclasses_replace(
-            state, tx_words=state.tx_words +
-            jnp.where(_is_sender(ctx, pattern),
-                      am.wire_words(state.segment.dtype, nwords), 0))
+        with _lint.layer("egress"):
+            state = gc.dataclasses_replace(
+                state, tx_words=state.tx_words +
+                jnp.where(_is_sender(ctx, pattern),
+                          am.wire_words(state.segment.dtype, nwords), 0))
         hdr_r, pay_r = _exchange(ctx, pattern, hdrs, buf)
-        state = gc.ingress_long_batch(ctx, state, hdr_r, pay_r, W)
-        # the final row is the only non-async one: it carries the ack
-        # lanes (defer ledger bump and/or piggybacked ack grant)
-        state = gc.ingress_ack_lanes(state, am.decode(hdr_r[-1]))
+        with _lint.layer("ingress"):
+            state = gc.ingress_long_batch(ctx, state, hdr_r, pay_r, W)
+            # the final row is the only non-async one: it carries the ack
+            # lanes (defer ledger bump and/or piggybacked ack grant)
+            state = gc.ingress_ack_lanes(state, am.decode(hdr_r[-1]))
         return _deliver_reply(ctx, state, pattern, am.decode(hdr_r[-1]),
                               asynchronous=asynchronous or defer_ack,
                               token=token, reply_via=reply_via)
@@ -609,26 +635,29 @@ def _counted_group_reply(ctx: ShoalContext, state: PgasState, union: Pattern,
     regardless of per-row tokens).  ``classes`` restricts which message
     classes count (``None`` = any non-NOP row).
     """
-    t_col = hdr_r[:, _I_TYPE]
-    cls = t_col & am._CLASS_MASK
-    if classes is None:
-        is_cls = cls != am.NOP
-    else:
-        is_cls = jnp.zeros(t_col.shape, bool)
-        for c in classes:
-            is_cls = is_cls | (cls == c)
-    needs = is_cls & ((t_col & (am.FLAG_ASYNC | am.FLAG_REPLY
-                                | am.FLAG_DEFER_ACK)) == 0)
-    cnt = jnp.sum(needs.astype(jnp.int32))
-    tok = (jnp.max(jnp.where(needs, hdr_r[:, _I_TOKEN], 0))
-           if token is None else token)
     rev = _reverse(union)
-    hdr = am.encode(type=am.make_type(am.SHORT, asynchronous=True),
-                    src=ctx.my_id(), dst=_dst_of(ctx, rev),
-                    handler=hd.H_ADD, token=tok, dst_addr=cnt)
-    hdr = _mask_nonparticipants(ctx, rev, hdr)
+    with _lint.layer("ingress"):
+        t_col = hdr_r[:, _I_TYPE]
+        cls = t_col & am._CLASS_MASK
+        if classes is None:
+            is_cls = cls != am.NOP
+        else:
+            is_cls = jnp.zeros(t_col.shape, bool)
+            for c in classes:
+                is_cls = is_cls | (cls == c)
+        needs = is_cls & ((t_col & (am.FLAG_ASYNC | am.FLAG_REPLY
+                                    | am.FLAG_DEFER_ACK)) == 0)
+        cnt = jnp.sum(needs.astype(jnp.int32))
+        tok = (jnp.max(jnp.where(needs, hdr_r[:, _I_TOKEN], 0))
+               if token is None else token)
+    with _lint.layer("egress"):
+        hdr = am.encode(type=am.make_type(am.SHORT, asynchronous=True),
+                        src=ctx.my_id(), dst=_dst_of(ctx, rev),
+                        handler=hd.H_ADD, token=tok, dst_addr=cnt)
+        hdr = _mask_nonparticipants(ctx, rev, hdr)
     hdr_back, _ = _exchange(ctx, rev, hdr, None)
-    return gc.ingress_short(ctx, state, am.decode(hdr_back))
+    with _lint.layer("ingress"):
+        return gc.ingress_short(ctx, state, am.decode(hdr_back))
 
 
 def put_long_multi(ctx: ShoalContext, state: PgasState, items, *,
@@ -745,7 +774,7 @@ def put_long_multi(ctx: ShoalContext, state: PgasState, items, *,
             nseg = len(segs)
             offs = jnp.asarray([o for o, _ in segs], jnp.int32)
             ws = jnp.asarray([w for _, w in segs], jnp.int32)
-            with _lint.scope(tag):
+            with _lint.scope(tag), _lint.layer("egress"):
                 hdrs = am.encode_batch(
                     nseg,
                     type=_seg_types(am.LONG, nseg,
@@ -767,10 +796,12 @@ def put_long_multi(ctx: ShoalContext, state: PgasState, items, *,
                               am.wire_words(state.segment.dtype, nw), 0))
         union = sorted(set(union))
         with _lint.scope(group_tag):
-            hdr_r, pay_r = _exchange(ctx, union,
-                                     jnp.concatenate(hdr_rows, axis=0),
-                                     jnp.concatenate(pay_rows, axis=0))
-            state = gc.ingress_stack(ctx, state, hdr_r, pay_r, W)
+            with _lint.layer("egress"):
+                hdr_all = jnp.concatenate(hdr_rows, axis=0)
+                pay_all = jnp.concatenate(pay_rows, axis=0)
+            hdr_r, pay_r = _exchange(ctx, union, hdr_all, pay_all)
+            with _lint.layer("ingress"):
+                state = gc.ingress_stack(ctx, state, hdr_r, pay_r, W)
             if acked and not defer_ack:
                 if reply_via is not None:
                     for i in grp:
@@ -806,7 +837,7 @@ def drain_deferred_acks(ctx: ShoalContext, state: PgasState,
     tag = _lint.emit("drain_deferred_acks", pattern, token=t_s,
                      acked=False, asynchronous=True, drains_deferred=True,
                      handler=hd.H_ADD, segment_words=ctx.segment_words)
-    with _lint.scope(tag):
+    with _lint.scope(tag), _lint.layer("sync"):
         count = state.deferred_acks[t_s]
         hdr = am.encode(type=am.make_type(am.SHORT, asynchronous=True),
                         src=ctx.my_id(), dst=_dst_of(ctx, pattern),
@@ -817,7 +848,8 @@ def drain_deferred_acks(ctx: ShoalContext, state: PgasState,
             jnp.where(sender, 0, state.deferred_acks[t_s]))
         state = gc.dataclasses_replace(state, deferred_acks=ledger)
         hdr_r, _ = _exchange(ctx, pattern, hdr, None)
-        return gc.ingress_short(ctx, state, am.decode(hdr_r))
+        with _lint.layer("ingress"):
+            return gc.ingress_short(ctx, state, am.decode(hdr_r))
 
 
 def _strides_may_overlap(stride, blk_words: int, nblocks: int) -> bool:
@@ -880,24 +912,27 @@ def put_long_strided(ctx: ShoalContext, state: PgasState, payload: jnp.ndarray,
                          nblocks - per * jnp.arange(nseg)).astype(jnp.int32)
         W = min(per, nblocks) * blk_words
         offs = jnp.arange(nseg, dtype=jnp.int32) * (per * blk_words)
-        hdrs = am.encode_batch(
-            nseg,
-            type=_seg_types(am.LONG, nseg, asynchronous=asynchronous,
-                            fifo=True, strided=True),
-            src=ctx.my_id(), dst=_dst_of(ctx, pattern),
-            nwords=nb * blk_words,
-            dst_addr=dst_addr + jnp.arange(nseg) * per * stride,
-            handler=handler, token=token, stride=stride,
-            blk_words=blk_words, nblocks=nb, seq=offs)
-        hdrs = _mask_nonparticipants(ctx, pattern, hdrs)
-        buf = gc.egress_batch(ctx, state, hdrs, payload, W)
-        state = gc.dataclasses_replace(
-            state, tx_words=state.tx_words +
-            jnp.where(_is_sender(ctx, pattern),
-                      am.wire_words(state.segment.dtype, nwords), 0))
+        with _lint.layer("egress"):
+            hdrs = am.encode_batch(
+                nseg,
+                type=_seg_types(am.LONG, nseg, asynchronous=asynchronous,
+                                fifo=True, strided=True),
+                src=ctx.my_id(), dst=_dst_of(ctx, pattern),
+                nwords=nb * blk_words,
+                dst_addr=dst_addr + jnp.arange(nseg) * per * stride,
+                handler=handler, token=token, stride=stride,
+                blk_words=blk_words, nblocks=nb, seq=offs)
+            hdrs = _mask_nonparticipants(ctx, pattern, hdrs)
+            buf = gc.egress_batch(ctx, state, hdrs, payload, W)
+            state = gc.dataclasses_replace(
+                state, tx_words=state.tx_words +
+                jnp.where(_is_sender(ctx, pattern),
+                          am.wire_words(state.segment.dtype, nwords), 0))
         hdr_r, pay_r = _exchange(ctx, pattern, hdrs, buf)
-        state = gc.ingress_strided_batch(ctx, state, hdr_r, pay_r, blk_words,
-                                         min(per, nblocks), ordered)
+        with _lint.layer("ingress"):
+            state = gc.ingress_strided_batch(ctx, state, hdr_r, pay_r,
+                                             blk_words, min(per, nblocks),
+                                             ordered)
         return _deliver_reply(ctx, state, pattern, am.decode(hdr_r[-1]),
                               asynchronous=asynchronous, token=token,
                               reply_via=reply_via)
@@ -955,34 +990,39 @@ def put_long_vectored(ctx: ShoalContext, state: PgasState,
         detail={} if alias is None else
         {"alias": f"blocks {alias[0]} and {alias[1]} overlap"})
     with _lint.scope(tag):
-        payload = jnp.concatenate([b.reshape(-1) for b in blocks])
-        t = am.make_type(am.LONG, asynchronous=asynchronous, fifo=True,
-                         vectored=True)
-        hdr = am.encode(type=t, src=ctx.my_id(), dst=_dst_of(ctx, pattern),
-                        nwords=nwords, handler=handler, token=token,
-                        nblocks=len(blocks))
-        hdr = _mask_nonparticipants(ctx, pattern, hdr)
-        buf = gc.egress(ctx, state, am.decode(hdr), payload, nwords)
-        state = gc.dataclasses_replace(
-            state, tx_words=state.tx_words +
-            jnp.where(_is_sender(ctx, pattern),
-                      am.wire_words(state.segment.dtype, nwords), 0))
-        addrs = jnp.asarray(dst_addrs, jnp.int32)
+        with _lint.layer("egress"):
+            payload = jnp.concatenate([b.reshape(-1) for b in blocks])
+            t = am.make_type(am.LONG, asynchronous=asynchronous, fifo=True,
+                             vectored=True)
+            hdr = am.encode(type=t, src=ctx.my_id(),
+                            dst=_dst_of(ctx, pattern), nwords=nwords,
+                            handler=handler, token=token,
+                            nblocks=len(blocks))
+            hdr = _mask_nonparticipants(ctx, pattern, hdr)
+            buf = gc.egress(ctx, state, am.decode(hdr), payload, nwords)
+            state = gc.dataclasses_replace(
+                state, tx_words=state.tx_words +
+                jnp.where(_is_sender(ctx, pattern),
+                          am.wire_words(state.segment.dtype, nwords), 0))
+            addrs = jnp.asarray(dst_addrs, jnp.int32)
         hdr_r, addrs_r, pay_r = _exchange(ctx, pattern, hdr, buf, extra=addrs)
-        h = am.decode(hdr_r)
-        off = 0
-        for i, b in enumerate(blocks):
-            w = int(b.size)
-            sub_hdr = am.Header(
-                type=h.type, src=h.src, dst=h.dst,
-                nwords=jnp.asarray(w, jnp.int32),
-                dst_addr=addrs_r[i], src_addr=h.src_addr, handler=h.handler,
-                token=h.token, stride=h.stride, blk_words=h.blk_words,
-                nblocks=h.nblocks, seq=h.seq, pb_token=h.pb_token,
-                pb_count=h.pb_count, epoch=h.epoch, crc=h.crc)
-            state = gc.ingress_long(ctx, state, sub_hdr,
-                                    lax.dynamic_slice(pay_r, (off,), (w,)), w)
-            off += w
+        with _lint.layer("ingress"):
+            h = am.decode(hdr_r)
+            off = 0
+            for i, b in enumerate(blocks):
+                w = int(b.size)
+                sub_hdr = am.Header(
+                    type=h.type, src=h.src, dst=h.dst,
+                    nwords=jnp.asarray(w, jnp.int32),
+                    dst_addr=addrs_r[i], src_addr=h.src_addr,
+                    handler=h.handler, token=h.token, stride=h.stride,
+                    blk_words=h.blk_words, nblocks=h.nblocks, seq=h.seq,
+                    pb_token=h.pb_token, pb_count=h.pb_count,
+                    epoch=h.epoch, crc=h.crc)
+                state = gc.ingress_long(
+                    ctx, state, sub_hdr,
+                    lax.dynamic_slice(pay_r, (off,), (w,)), w)
+                off += w
         return _deliver_reply(ctx, state, pattern, h,
                               asynchronous=asynchronous, token=token,
                               reply_via=reply_via)
@@ -1011,17 +1051,22 @@ def get_medium(ctx: ShoalContext, state: PgasState, pattern: Pattern,
         nseg, W = len(segs), segs[0][1]
         offs = jnp.asarray([o for o, _ in segs], jnp.int32)
         ws = jnp.asarray([w for _, w in segs], jnp.int32)
-        hdrs = am.encode_batch(
-            nseg, type=am.make_type(am.MEDIUM, get=True),
-            src=ctx.my_id(), dst=_dst_of(ctx, pattern), nwords=ws,
-            src_addr=src_addr + offs, token=token, seq=offs)
-        hdrs = _mask_nonparticipants(ctx, pattern, hdrs)
+        with _lint.layer("egress"):
+            hdrs = am.encode_batch(
+                nseg, type=am.make_type(am.MEDIUM, get=True),
+                src=ctx.my_id(), dst=_dst_of(ctx, pattern), nwords=ws,
+                src_addr=src_addr + offs, token=token, seq=offs)
+            hdrs = _mask_nonparticipants(ctx, pattern, hdrs)
         hdr_r, _ = _exchange(ctx, pattern, hdrs, None)
-        state, resp_rows, data_rows = gc.serve_get_batch(ctx, state, hdr_r, W)
+        with _lint.layer("ingress"):
+            state, resp_rows, data_rows = gc.serve_get_batch(ctx, state,
+                                                             hdr_r, W)
         back_hdr, back_data = _exchange(ctx, _reverse(pattern), resp_rows,
                                         data_rows)
-        state = gc.ingress_reply(state, am.decode(back_hdr[-1]))
-        state, data = gc.ingress_medium_batch(state, back_hdr, back_data, W)
+        with _lint.layer("ingress"):
+            state = gc.ingress_reply(state, am.decode(back_hdr[-1]))
+            state, data = gc.ingress_medium_batch(state, back_hdr,
+                                                  back_data, W)
         return state, data[:nwords]
 
 
@@ -1043,22 +1088,27 @@ def get_long(ctx: ShoalContext, state: PgasState, pattern: Pattern,
         nseg, W = len(segs), segs[0][1]
         offs = jnp.asarray([o for o, _ in segs], jnp.int32)
         ws = jnp.asarray([w for _, w in segs], jnp.int32)
-        hdrs = am.encode_batch(
-            nseg, type=am.make_type(am.LONG, get=True),
-            src=ctx.my_id(), dst=_dst_of(ctx, pattern), nwords=ws,
-            src_addr=src_addr + offs, dst_addr=dst_addr + offs,
-            token=token, handler=handler, seq=offs)
-        hdrs = _mask_nonparticipants(ctx, pattern, hdrs)
+        with _lint.layer("egress"):
+            hdrs = am.encode_batch(
+                nseg, type=am.make_type(am.LONG, get=True),
+                src=ctx.my_id(), dst=_dst_of(ctx, pattern), nwords=ws,
+                src_addr=src_addr + offs, dst_addr=dst_addr + offs,
+                token=token, handler=handler, seq=offs)
+            hdrs = _mask_nonparticipants(ctx, pattern, hdrs)
         hdr_r, _ = _exchange(ctx, pattern, hdrs, None)
-        state, resp_rows, data_rows = gc.serve_get_batch(ctx, state, hdr_r, W)
+        with _lint.layer("ingress"):
+            state, resp_rows, data_rows = gc.serve_get_batch(ctx, state,
+                                                             hdr_r, W)
         back_hdr, back_data = _exchange(ctx, _reverse(pattern), resp_rows,
                                         data_rows)
-        state = gc.ingress_reply(state, am.decode(back_hdr[-1]))
-        # land in local segment through the handler (class LONG on the wire)
-        is_rep = (back_hdr[:, 0] & am.FLAG_REPLY) != 0
-        land_rows = back_hdr.at[:, 0].set(
-            jnp.where(is_rep, am.LONG, am.NOP).astype(jnp.int32))
-        return gc.ingress_long_batch(ctx, state, land_rows, back_data, W)
+        with _lint.layer("ingress"):
+            state = gc.ingress_reply(state, am.decode(back_hdr[-1]))
+            # land in local segment through the handler (class LONG on
+            # the wire)
+            is_rep = (back_hdr[:, 0] & am.FLAG_REPLY) != 0
+            land_rows = back_hdr.at[:, 0].set(
+                jnp.where(is_rep, am.LONG, am.NOP).astype(jnp.int32))
+            return gc.ingress_long_batch(ctx, state, land_rows, back_data, W)
 
 
 # --------------------------------------------------------------------------
@@ -1071,8 +1121,9 @@ def barrier(ctx: ShoalContext, state: PgasState) -> PgasState:
     no kernel's successor ops can be scheduled before every kernel's
     contribution arrives.  The barrier epoch counts completions."""
     tag = _lint.emit("barrier", [])
-    with _lint.scope(tag):
-        arrived = lax.psum(jnp.ones((), jnp.int32), ctx.axes)
+    with _lint.scope(tag), _lint.layer("sync"):
+        with _lint.layer("wire"):
+            arrived = lax.psum(jnp.ones((), jnp.int32), ctx.axes)
         epoch = state.barrier_epoch + (arrived // arrived)  # data-dependent
         return gc.dataclasses_replace(state, barrier_epoch=epoch)
 
@@ -1101,7 +1152,7 @@ def wait_replies(ctx: ShoalContext, state: PgasState, token, n, *,
     """
     tag = _lint.emit("wait_replies", [], token=_lint.static_int(token),
                      wait_n=_lint.static_int(n), timeout=timeout)
-    with _lint.scope(tag):
+    with _lint.scope(tag), _lint.layer("sync"):
         token = jnp.clip(jnp.asarray(token, jnp.int32), 0, hd.NUM_TOKENS - 1)
         have = state.credits[token]
         if timeout:

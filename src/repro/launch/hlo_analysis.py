@@ -15,12 +15,19 @@ collective traffic, so we parse ``compiled.as_text()``:
 
 Group size is parsed from ``replica_groups={{...}}`` or the iota form
 ``replica_groups=[G,N]<=[...]``.
+
+:func:`op_layers` maps each instruction to the layer scope
+(:func:`repro.analysis.trace.layer`) its ``op_name`` metadata names: a
+profiler's device events carry only the instruction's name, so this map
+turns a profile of a Shoal program into layers.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import re
+
+from repro.analysis.trace import LAYER_PREFIX, LAYERS
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -205,3 +212,27 @@ def parse_collectives(hlo: str) -> CollectiveStats:
     return CollectiveStats(ops=ops, shape_bytes=shape_bytes,
                            wire_bytes=wire, by_kind=by_kind,
                            dot_flops=dot_flops)
+
+
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_OP_NAME_RE = re.compile(r'\bop_name="([^"]*)"')
+_LAYER_RE = re.compile(r"(?<![\w.])" + re.escape(LAYER_PREFIX)
+                       + r"(" + "|".join(LAYERS) + r")(?![\w.])")
+
+
+def op_layers(hlo: str) -> dict[str, str | None]:
+    """``{instruction name: layer}`` over every computation of a compiled
+    module's text (``compiled.as_text()``): the innermost ``layer.<name>``
+    scope in the instruction's ``op_name`` metadata, whatever tags
+    (``shoal.<op>#e<seq>``) or transforms surround it.  ``None`` where
+    the metadata names no layer, or where there is none, as on the
+    copies and fusions the compiler makes itself."""
+    out: dict[str, str | None] = {}
+    for line in hlo.splitlines():
+        m = _INSTR_RE.match(line)
+        if not m:
+            continue
+        op = _OP_NAME_RE.search(line)
+        found = _LAYER_RE.findall(op.group(1)) if op else []
+        out[m.group(1)] = found[-1] if found else None
+    return out

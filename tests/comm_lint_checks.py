@@ -226,6 +226,10 @@ def test_registry_entries_clean():
         if name != "moe-dispatch":     # moe uses no shoal ops directly
             check(f"entry {name} tags recoverable from jaxpr",
                   rep.tags_recovered > 0 and rep.n_events > 0)
+        if name == "jacobi":           # layer scopes add no shoal.* tag
+            check("entry jacobi recovers one tag an event",
+                  rep.tags_recovered == rep.n_events,
+                  f"({rep.n_events} events, {rep.tags_recovered} tags)")
 
 
 # --------------------------------------------------------------------------

@@ -3,7 +3,8 @@
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.launch.hlo_analysis import parse_collectives, split_computations
+from repro.launch.hlo_analysis import (op_layers, parse_collectives,
+                                      split_computations)
 from repro.runtime.router import Router
 from repro.runtime.topology import ClusterSpec, neighbors_ring, pairwise
 from repro.runtime.transport import (TCP, UDP, LinkClass, model_latency_s,
@@ -62,6 +63,67 @@ def test_parser_trip_weighting():
 def test_split_computations_names():
     comps = split_computations(MINI_HLO)
     assert set(comps) == {"add.1", "body.2", "cond.3", "main.4"}
+
+
+LAYERED_HLO = """
+ENTRY %main.9 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %collective-permute-start.2 = (f32[8], f32[8]) collective-permute-start(%p), metadata={op_name="jit(f)/shard_map/shoal.drain_deferred_acks#e5/layer.sync/layer.wire/ppermute" stack_frame_id=3}
+  %psum_invariant.10 = s32[] all-reduce(%c), to_apply=%add, metadata={op_name="jit(f)/while/body/shoal.barrier#e4/layer.sync/layer.wire/psum_invariant"}
+  %neg.46 = s32[] negate(%c), metadata={op_name="jit(f)/while/body/shoal.wait_replies#e2/layer.sync/neg"}
+  %fusion.46 = f32[8]{0} fusion(%p), kind=kLoop, calls=%fc, metadata={op_name="jit(f)/while/body/layer.compute/jit(jacobi_step_pallas)/slice"}
+  %while.203 = (s32[], f32[8]) while(%t), condition=%c.1, body=%b.1, metadata={op_name="jit(f)/shoal.put_long_multi#e0/layer.ingress/while"}
+  %add.9 = f32[8]{0} add(%p, %p), metadata={op_name="jit(f)/layer.compute/transpose(jvp(layer.egress))/add"}
+  %copy.194 = f32[8]{0} copy(%p)
+  %mul.7 = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(f)/shoal.put_long#e1/mul"}
+  %user.1 = f32[8]{0} add(%p, %p), metadata={op_name="jit(f)/mylayer.wire/layer.computes/add"}
+  ROOT %out = f32[8]{0} copy(%p), metadata={op_name="jit(f)/layer.egress/copy"}
+}
+"""
+
+
+def test_op_layers_innermost_scope_wins():
+    layers = op_layers(LAYERED_HLO)
+    assert layers["collective-permute-start.2"] == "wire"
+    assert layers["psum_invariant.10"] == "wire"
+    assert layers["neg.46"] == "sync"
+    assert layers["fusion.46"] == "compute"
+    assert layers["while.203"] == "ingress"     # #e<seq> tags ignored
+    assert layers["add.9"] == "egress"          # inside a transform's name
+    assert layers["out"] == "egress"            # ROOT instructions too
+
+
+def test_op_layers_no_metadata_or_no_layer_is_none():
+    layers = op_layers(LAYERED_HLO)
+    assert layers["copy.194"] is None            # compiler-made: no metadata
+    assert layers["p"] is None
+    assert layers["mul.7"] is None               # a shoal tag, no layer
+    assert layers["user.1"] is None              # not a layer scope's name
+    assert "main.9" not in layers                # computations are not ops
+
+
+def test_layer_scopes_reach_metadata_but_not_lint_tags():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.analysis import trace
+
+    def f(x):
+        with trace.scope("shoal.put_long#e0"), trace.layer("egress"):
+            y = x * 2
+        with trace.layer("sync"), trace.layer("wire"):
+            return y + 1
+
+    x = jnp.ones(4)
+    assert trace.recover_tags(jax.make_jaxpr(f)(x)) == {"shoal.put_long#e0": 1}
+    layers = op_layers(jax.jit(f).lower(x).compile().as_text())
+    assert {v for v in layers.values() if v} == {"egress", "wire"}
+    # a fusion carries its root's metadata: the multiply fused into the
+    # add is charged to the add's layer
+    fusions = [k for k in layers if "fusion" in k]
+    assert fusions and all(layers[k] == "wire" for k in fusions)
+    with pytest.raises(ValueError, match="unknown layer"):
+        trace.layer("kernels")
 
 
 # -- transport / router -------------------------------------------------------

@@ -7,7 +7,9 @@ topology is described inside a fixture: only one process at a time may
 load the TPU library, so nothing here touches it at import.
 """
 
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,8 +56,20 @@ def test_jacobi_stencil_4096_compiles(topo):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_jacobi_app_4_kernels_compiles(kernel_mesh, monkeypatch, use_pallas):
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*?\s([a-z][\w\-]*)\((.*)")
+
+
+def _instructions(hlo: str) -> dict:
+    """``{name: (opcode, rest of the line)}`` of a compiled module."""
+    out = {}
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            out[m.group(1)] = (m.group(2), m.group(3))
+    return out
+
+
+def _jacobi_4_kernels_hlo(monkeypatch, kernel_mesh, use_pallas):
     import repro.apps.jacobi as jacobi_app
     monkeypatch.setattr(jacobi_app, "make_cpu_mesh", kernel_mesh)
     app = jacobi_app.JacobiApp(n=4096, kernels=4, iters=2,
@@ -63,10 +77,53 @@ def test_jacobi_app_4_kernels_compiles(kernel_mesh, monkeypatch, use_pallas):
     blocks = jax.ShapeDtypeStruct(
         (4, app.rows, app.n), jnp.float32,
         sharding=NamedSharding(app.mesh, P(("kernel",))))
-    hlo = app.build().lower(_state_shapes(app.ctx),
-                            blocks).compile().as_text()
+    return app.build().lower(_state_shapes(app.ctx),
+                             blocks).compile().as_text()
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_jacobi_app_4_kernels_compiles(kernel_mesh, monkeypatch, use_pallas):
+    from repro.launch.hlo_analysis import op_layers
+
+    hlo = _jacobi_4_kernels_hlo(monkeypatch, kernel_mesh, use_pallas)
     assert "collective-permute" in hlo
     assert ("tpu_custom_call" in hlo) == use_pallas
+    # the layer scopes reach the executed module's instructions
+    layers, instrs = op_layers(hlo), _instructions(hlo)
+    permutes = [k for k, (op, _) in instrs.items()
+                if op.startswith("collective-permute")]
+    assert permutes and all(layers[k] == "wire" for k in permutes)
+    assert any(op == "while" and layers[k] == "ingress"
+               for k, (op, _) in instrs.items())
+    if use_pallas:
+        calls = [(k, rest) for k, (op, rest) in instrs.items()
+                 if op == "custom-call" and "tpu_custom_call" in rest]
+        assert calls and all(layers[k] == "compute" for k, _ in calls)
+        # the kernel's shifted views are fusions feeding the call
+        views = {a for _, rest in calls
+                 for a in re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+                 if instrs[a][0] == "fusion"}
+        assert len(views) >= 2 and all(layers[v] == "compute" for v in views)
+
+
+def test_layer_scopes_change_no_instruction(kernel_mesh, monkeypatch):
+    """Scopes are metadata: with them off, the compiled module is the
+    same instruction for instruction (the Pallas kernel's body too)."""
+    import repro.apps.jacobi as jacobi_app
+    from repro.analysis import trace
+
+    def strip(hlo):
+        return [re.sub(r"(?<![\w])metadata=\{[^}]*\}", "", rest)
+                for _, rest in _instructions(hlo).values()]
+
+    scoped = _jacobi_4_kernels_hlo(monkeypatch, kernel_mesh, True)
+    off = lambda name: contextlib.nullcontext()  # noqa: E731
+    monkeypatch.setattr(trace, "layer", off)
+    monkeypatch.setattr(jacobi_app, "layer", off)
+    plain = _jacobi_4_kernels_hlo(monkeypatch, kernel_mesh, True)
+    assert "layer." in scoped and "layer." not in plain
+    assert list(_instructions(scoped)) == list(_instructions(plain))
+    assert strip(scoped) == strip(plain)
 
 
 def test_local_put_long_and_mixed_mailbox_compile(kernel_mesh):

@@ -23,8 +23,7 @@ compiled HLO's ``op_name`` metadata, so an instruction -- and through
 can be charged to its layer.  The names are fixed (:data:`LAYERS`):
 
 * ``compute``: the application's own compute step (for Jacobi, the
-  stencil: its halo-row selects, the Pallas kernel's shifted views and
-  call);
+  stencil: its halo-row selects and the Pallas kernel's call);
 * ``egress``: packet build (header encode, piggyback lane, NOP masking,
   the GAScore's payload egress, the fused packet);
 * ``wire``: the collectives that cross the links, and nothing else;

@@ -10,10 +10,12 @@ import jax.numpy as jnp
 from repro.kernels.jacobi.jacobi import jacobi_step_pallas
 from repro.kernels.jacobi.ref import jacobi_step_ref
 
-# Bytes of one (block_rows, N) band.  Four double-buffered bands plus
-# the kernel's band-sized temporaries must fit v5e's scoped VMEM; 4 MiB
-# bands (256 rows at N=4096 f32) do not, 1 MiB bands do.
-_BAND_BYTES = 1 << 20
+# Bytes of one (block_rows, N) band.  The input and output bands, each
+# double buffered, plus the kernel's band-sized temporaries must fit
+# v5e's scoped VMEM; 4 MiB bands (256 rows at N=4096 f32) do not, 2 MiB
+# bands do, and over-read half as much as 1 MiB bands for the two 8-row
+# halo tiles each band step fetches (faster on a v5e).
+_BAND_BYTES = 2 << 20
 
 
 def _pick_block_rows(m: int, n: int, itemsize: int) -> int:

@@ -50,9 +50,31 @@ def test_jacobi_band_step_matches_whole_grid(bands):
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("block_rows", [8, 16, 32])
+@pytest.mark.parametrize("row0", [16, 32])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_jacobi_step_pallas_halo_rows(block_rows, row0, dtype):
+    """The kernel called directly on a 32-row band of a 64-row grid:
+    grid steps inside the band take their halo rows from the neighbouring
+    8-row tiles, the first and last from ``top``/``bottom``, and the
+    Dirichlet mask follows the global rows (the band at row 32 ends on
+    the grid's last row)."""
+    from repro.kernels.jacobi.jacobi import jacobi_step_pallas
+    m, m_total, n = 32, 64, 128
+    g = jnp.asarray(RNG.standard_normal((m_total + 1, n)) + 1.0, dtype)
+    band, top, bottom = g[row0:row0 + m], g[row0 - 1], g[row0 + m]
+    got = jacobi_step_pallas(band, top, bottom, row0, m_total=m_total,
+                             block_rows=block_rows, interpret=True)
+    want = jacobi_step_ref(g[:m_total])[row0:row0 + m]
+    tol = 1e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
 def test_jacobi_block_rows_fit_tile_and_vmem():
     from repro.kernels.jacobi.ops import _BAND_BYTES, _pick_block_rows
-    assert _pick_block_rows(4096, 4096, 4) == 64   # 256 overflows VMEM
+    assert _pick_block_rows(4096, 4096, 4) == 128  # 256 overflows VMEM
     for m, n in [(4096, 4096), (1024, 4096), (2048, 2048), (512, 512),
                  (40, 128), (24, 1 << 16)]:
         b = _pick_block_rows(m, n, 4)

@@ -56,6 +56,18 @@ def test_jacobi_stencil_4096_compiles(topo):
     assert "tpu_custom_call" in hlo
 
 
+def test_jacobi_stencil_4096_bf16_compiles(topo):
+    """Mosaic rotates 32-bit data only: the kernel's row shifts of a
+    bfloat16 band must go through f32."""
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.kernels.jacobi import jacobi_step
+    x = jax.ShapeDtypeStruct((4096, 4096), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+    hlo = jax.jit(jacobi_step).lower(x).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*?\s([a-z][\w\-]*)\((.*)")
 
 
@@ -69,23 +81,54 @@ def _instructions(hlo: str) -> dict:
     return out
 
 
-def _jacobi_4_kernels_hlo(monkeypatch, kernel_mesh, use_pallas):
+_SHAPE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = [a-z]\w*\[([\d,]*)\]",
+                    re.M)
+
+
+def _jacobi_app_hlo(monkeypatch, kernel_mesh, use_pallas, kernels=4):
     import repro.apps.jacobi as jacobi_app
     monkeypatch.setattr(jacobi_app, "make_cpu_mesh", kernel_mesh)
-    app = jacobi_app.JacobiApp(n=4096, kernels=4, iters=2,
+    app = jacobi_app.JacobiApp(n=4096, kernels=kernels, iters=2,
                                use_pallas=use_pallas)
     blocks = jax.ShapeDtypeStruct(
-        (4, app.rows, app.n), jnp.float32,
+        (kernels, app.rows, app.n), jnp.float32,
         sharding=NamedSharding(app.mesh, P(("kernel",))))
     return app.build().lower(_state_shapes(app.ctx),
                              blocks).compile().as_text()
+
+
+def _assert_stencil_reads_band_once(hlo, rows, n):
+    """The stencil's call has one band-sized operand, the band the solve
+    loop carries (through copies alone), and no band-sized pad or fusion
+    of layer ``compute`` is built beside it."""
+    from repro.launch.hlo_analysis import op_layers
+
+    layers, instrs = op_layers(hlo), _instructions(hlo)
+    dims = {k: [int(d) for d in s.split(",") if d]
+            for k, s in _SHAPE.findall(hlo)}
+    band = lambda k: (len(dims.get(k, ())) == 2  # noqa: E731
+                      and dims[k][0] >= rows - 1 and dims[k][1] == n)
+    calls = [rest for op, rest in instrs.values()
+             if op == "custom-call" and "tpu_custom_call" in rest]
+    assert calls
+    for rest in calls:
+        bands = {a for a in re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+                 if band(a)}
+        assert len(bands) == 1, bands
+        a = bands.pop()
+        while instrs[a][0] in ("copy", "copy-start", "copy-done", "bitcast"):
+            a = re.findall(r"%([\w.\-]+)", instrs[a][1])[0]
+        assert instrs[a][0] == "get-tuple-element", (a, instrs[a])
+    views = [k for k, (op, _) in instrs.items()
+             if op in ("pad", "fusion") and layers[k] == "compute" and band(k)]
+    assert not views, views
 
 
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_jacobi_app_4_kernels_compiles(kernel_mesh, monkeypatch, use_pallas):
     from repro.launch.hlo_analysis import op_layers
 
-    hlo = _jacobi_4_kernels_hlo(monkeypatch, kernel_mesh, use_pallas)
+    hlo = _jacobi_app_hlo(monkeypatch, kernel_mesh, use_pallas)
     assert "collective-permute" in hlo
     assert ("tpu_custom_call" in hlo) == use_pallas
     # the layer scopes reach the executed module's instructions
@@ -99,11 +142,15 @@ def test_jacobi_app_4_kernels_compiles(kernel_mesh, monkeypatch, use_pallas):
         calls = [(k, rest) for k, (op, rest) in instrs.items()
                  if op == "custom-call" and "tpu_custom_call" in rest]
         assert calls and all(layers[k] == "compute" for k, _ in calls)
-        # the kernel's shifted views are fusions feeding the call
-        views = {a for _, rest in calls
-                 for a in re.findall(r"%([\w.\-]+)", rest.split(")")[0])
-                 if instrs[a][0] == "fusion"}
-        assert len(views) >= 2 and all(layers[v] == "compute" for v in views)
+        _assert_stencil_reads_band_once(hlo, 4096 // 4, 4096)
+
+
+def test_jacobi_app_1_kernel_stencil_reads_band_once(kernel_mesh,
+                                                    monkeypatch):
+    """On one kernel the whole 64-MiB grid is the band: the kernel still
+    reads it as its only band-sized operand, with no shifted copies."""
+    hlo = _jacobi_app_hlo(monkeypatch, kernel_mesh, True, kernels=1)
+    _assert_stencil_reads_band_once(hlo, 4096, 4096)
 
 
 def test_layer_scopes_change_no_instruction(kernel_mesh, monkeypatch):
@@ -116,11 +163,11 @@ def test_layer_scopes_change_no_instruction(kernel_mesh, monkeypatch):
         return [re.sub(r"(?<![\w])metadata=\{[^}]*\}", "", rest)
                 for _, rest in _instructions(hlo).values()]
 
-    scoped = _jacobi_4_kernels_hlo(monkeypatch, kernel_mesh, True)
+    scoped = _jacobi_app_hlo(monkeypatch, kernel_mesh, True)
     off = lambda name: contextlib.nullcontext()  # noqa: E731
     monkeypatch.setattr(trace, "layer", off)
     monkeypatch.setattr(jacobi_app, "layer", off)
-    plain = _jacobi_4_kernels_hlo(monkeypatch, kernel_mesh, True)
+    plain = _jacobi_app_hlo(monkeypatch, kernel_mesh, True)
     assert "layer." in scoped and "layer." not in plain
     assert list(_instructions(scoped)) == list(_instructions(plain))
     assert strip(scoped) == strip(plain)

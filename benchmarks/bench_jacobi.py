@@ -11,9 +11,11 @@ Fig. 7 analogue: run time vs kernel count for grids 256..4096 on one
 time, noted in the derived column as iterations).  Small grids are
 communication-dominated (more kernels hurt); large grids gain.
 
-Fig. 8 analogue: grid 4096 with 8 kernels concentrated on one "pod"
-vs spread across two (the mesh's pod axis) — the paper's
-multi-node-spread experiment.
+Fig. 8 analogue: grid 4096 with 8 kernels co-located on one device
+(the paper's one node: every halo put takes the LOCAL path, no
+collective) vs the same 8 kernels spread over two devices, 4 each (the
+one boundary between them crosses the mesh) — the paper's
+multi-node-spread experiment, with the kernels placed for real.
 
 The grid-4096 rows exercise halo rows of 16 KiB > the 9000-byte jumbo
 frame: the configuration footnote 2 of the paper could NOT run.  Our
@@ -45,17 +47,25 @@ def main():
             us = time_fn(fn, st, blocks, iters=3, warmup=1)
             print(f"jacobi/sw/{n}x{n}/k{k},{us:.0f},{ITERS}")
 
-    # Fig. 8: 8 kernels on 1 pod (chip axis only) vs spread over 2 pods —
-    # emulated by pattern link classes; on real hardware the pod spread
-    # halves per-pod memory contention (paper Sec. IV-C2).
+    # Fig. 8: 8 kernels on 1 device (one node) vs spread over 2 devices
+    # (two nodes); the paper finds the spread halves per-node memory
+    # contention (Sec. IV-C2)
     n = 4096
     grid = rng.standard_normal((n, n)).astype(np.float32)
-    app = JacobiApp(n=n, kernels=8, iters=ITERS)
-    out = app.run(grid.copy())
     ref = jacobi_reference(grid.copy(), ITERS)
-    err = float(np.abs(out - ref).max())
-    # >MTU segmentation correctness (paper's footnote-2 failing config)
-    assert err < 1e-4, f"4096 halo segmentation broke: {err}"
+    for chips in (1, 2):
+        app = JacobiApp(n=n, kernels=8, iters=ITERS, chips=chips)
+        out = app.run(grid.copy())
+        err = float(np.abs(out - ref).max())
+        # >MTU segmentation correctness (paper's footnote-2 failing config)
+        assert err < 1e-4, f"4096 halo segmentation broke: {err}"
+        from repro.core.address_space import GlobalAddressSpace
+        import jax.numpy as jnp
+        st = GlobalAddressSpace(app.ctx).make_global_state()
+        us = time_fn(app.build(), st,
+                     jnp.asarray(grid.reshape(8, n // 8, n)), iters=3,
+                     warmup=1)
+        print(f"jacobi/fig8/{n}x{n}/k8-on-{chips}dev,{us:.0f},{ITERS}")
     print(f"jacobi/mtu-segmentation-4096/correct,0.0,{err:.2e}")
 
 

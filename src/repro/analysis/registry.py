@@ -74,6 +74,24 @@ def _build_jacobi_steady():
     return fn, (st, blocks), lambda: fn.lower(st, blocks).compile().as_text()
 
 
+def _build_jacobi_colocated():
+    """The Jacobi steady state with its 8 kernels on ONE device: 4
+    piggybacked iterations whose halo puts, ack lanes, drains and
+    barriers all stay inside the device (the LOCAL path)."""
+    import jax.numpy as jnp
+
+    from repro.apps.jacobi import JacobiApp
+    from repro.core.address_space import GlobalAddressSpace
+
+    app = JacobiApp(n=64, kernels=8, iters=4, transport=_tiny_tcp(),
+                    piggyback=True, chips=1)
+    gas = GlobalAddressSpace(app.ctx)
+    st = gas.make_global_state()
+    blocks = jnp.zeros((8, 64 // 8, 64), jnp.float32)
+    fn = app.build()
+    return fn, (st, blocks), lambda: fn.lower(st, blocks).compile().as_text()
+
+
 def _build_actors_mailbox():
     """The actor-layer headline: 1024 4-word sends -> one flush."""
     import jax
@@ -205,6 +223,9 @@ ENTRIES: tuple[Entry, ...] = (
     Entry("jacobi-steady",
           "Jacobi steady state: 4 piggybacked iterations, <=2 CPs/iter",
           8, _build_jacobi_steady),
+    Entry("jacobi-colocated",
+          "Jacobi steady state, 8 kernels on 1 device: no collective",
+          1, _build_jacobi_colocated),
     Entry("actors-mailbox", "1024 4-word mailbox sends, one flush + wait",
           8, _build_actors_mailbox),
     Entry("moe-dispatch", "MoE a2a expert dispatch, mesh (2,4), 2 layers",

@@ -27,6 +27,8 @@ can be charged to its layer.  The names are fixed (:data:`LAYERS`):
 * ``egress``: packet build (header encode, piggyback lane, NOP masking,
   the GAScore's payload egress, the fused packet);
 * ``wire``: the collectives that cross the links, and nothing else;
+* ``local``: the moves of packets between kernels on one device (the
+  LOCAL path, which issues no collective);
 * ``ingress``: the GAScore's ingress of data, replies and ack lanes
   (the fused packet's unpacking included);
 * ``sync``: barrier, ``wait_replies`` and the deferred-ack drain.
@@ -139,10 +141,12 @@ class CommEvent:
 
 
 class Recorder:
-    """Collects :class:`CommEvent`s while installed (see :func:`record`)."""
+    """Collects :class:`CommEvent`s while installed (see :func:`record`),
+    and the packets and bytes each link class carried (:func:`carried`)."""
 
     def __init__(self) -> None:
         self.events: list[CommEvent] = []
+        self.links: dict[str, dict[str, int]] = {}
 
     def next_seq(self) -> int:
         return len(self.events)
@@ -220,12 +224,23 @@ def emit(op: str, pattern, **kw) -> str:
     return f"shoal.{op}#e{_TAG_COUNTER[0] - 1}"
 
 
+def carried(link: str, packets: int, nbytes: int) -> None:
+    """Count one exchange's packets and bytes on a link class (``LOCAL``
+    between kernels on one device, ``ICI`` between devices) while a
+    :func:`record` block is active."""
+    if _RECORDERS:
+        tally = _RECORDERS[-1].links.setdefault(
+            link, {"packets": 0, "bytes": 0})
+        tally["packets"] += packets
+        tally["bytes"] += nbytes
+
+
 def scope(tag: str):
     """Named scope wrapping an op's equations with its event tag."""
     return jax.named_scope(tag)
 
 
-LAYERS = ("compute", "egress", "wire", "ingress", "sync")
+LAYERS = ("compute", "egress", "wire", "local", "ingress", "sync")
 LAYER_PREFIX = "layer."
 
 

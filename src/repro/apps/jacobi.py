@@ -12,6 +12,13 @@ The grid (N x N) is row-partitioned over kernels.  Each iteration:
 
 Segment layout per kernel: [0, N) = top halo row, [N, 2N) = bottom halo.
 
+Placement: one kernel per chip by default; ``chips`` puts
+``kernels / chips`` consecutive kernels on each chip, as the paper's
+Figs. 7-8 put several kernels on one node.  Halo puts between kernels
+on one chip take the LOCAL path (no collective), the others cross ICI,
+and the compiled stencil runs once per chip over the stack of its
+kernels' bands.
+
 The paper's footnote-2 limitation — at grid 4096 a halo row exceeds the
 9000-byte jumbo frame and their runs *fail* — is handled here by the
 transparent >MTU segmentation in :func:`repro.core.ops.put_long_multi`;
@@ -38,8 +45,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from jax import shard_map
 import numpy as np
 
 from repro.analysis.trace import layer
@@ -62,14 +67,21 @@ class JacobiApp:
     interpret: bool = False   # Pallas interpret mode (CPU runs)
     piggyback: bool = True    # defer halo acks onto the next iteration's
                               # reverse-link data packet (acked transports)
+    chips: int | None = None  # devices the kernels share; None: one each
 
     def __post_init__(self):
         assert self.n % self.kernels == 0
+        if self.chips is None:
+            self.chips = self.kernels
+        if self.kernels % self.chips:
+            raise ValueError(f"{self.kernels} kernels do not split evenly "
+                             f"over {self.chips} chips")
         self.rows = self.n // self.kernels
-        self.mesh = make_cpu_mesh(self.kernels, ("kernel",))
+        self.mesh = make_cpu_mesh(self.chips, ("kernel",))
         self.ctx = ShoalContext(mesh=self.mesh, axes=("kernel",),
                                 transport=self.transport,
-                                segment_words=2 * self.n)
+                                segment_words=2 * self.n,
+                                kernels_per_device=self.kernels // self.chips)
         k = self.kernels
         self.up = [(i, i - 1) for i in range(1, k)]      # send top row up
         self.down = [(i, i + 1) for i in range(k - 1)]   # send bottom row down
@@ -195,9 +207,34 @@ class JacobiApp:
             return (jax.tree.map(lambda x: x[None], st), block[None])
 
         spec = P(("kernel",))
-        fn = shard_map(per_kernel, mesh=self.mesh,
-                           in_specs=(spec, spec), out_specs=(spec, spec))
+        fn = ctx.kernel_map(per_kernel, in_specs=(spec, spec),
+                            out_specs=(spec, spec))
         return jax.jit(fn)
+
+    def links_per_iteration(self) -> dict:
+        """Packets and bytes one iteration ships, by link class (``LOCAL``
+        between kernels on one chip, ``ICI`` between chips), counted
+        from its trace: ``{class: {"packets": p, "bytes": b}}``."""
+        from jax.sharding import PartitionSpec as P
+
+        from repro.analysis import trace
+
+        def one(st, block):
+            st = jax.tree.map(lambda x: x[0], st)
+            st, block = self._iteration(st, block[0], jnp.ones((), jnp.int32))
+            return jax.tree.map(lambda x: x[None], st), block[None]
+
+        spec = P(("kernel",))
+        fn = self.ctx.kernel_map(one, in_specs=(spec, spec),
+                                 out_specs=(spec, spec))
+        st = jax.eval_shape(lambda: jax.tree.map(
+            lambda x: jnp.zeros((self.kernels,) + x.shape, x.dtype),
+            self.ctx.make_state()))
+        blocks = jax.ShapeDtypeStruct((self.kernels, self.rows, self.n),
+                                      jnp.float32)
+        with trace.record() as rec:
+            jax.eval_shape(fn, st, blocks)
+        return rec.links
 
     def run(self, grid: np.ndarray):
         """Run on a host grid (n, n); returns the final grid."""

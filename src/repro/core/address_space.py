@@ -9,7 +9,11 @@ is known to the programmer" (Sec. II-A3).
 
 Host-side helpers move data between a NumPy/global view and the
 per-device segments (sharded ``jax.Array``), which is how applications
-(e.g. Jacobi) load initial conditions and read results back.
+(e.g. Jacobi) load initial conditions and read results back.  The
+global view is laid out by kernel, ``(num_kernels, ...)``, and split
+over the devices: a device holds the ``kernels_per_device`` consecutive
+kernels it hosts, so kernel ``k`` owns the same segment wherever it
+lands (:meth:`GlobalAddressSpace.placement`).
 """
 
 from __future__ import annotations
@@ -20,8 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from jax import shard_map
 
 from repro.core.state import PgasState, ShoalContext
 
@@ -43,6 +45,10 @@ class GlobalAddressSpace:
 
     def owner_of(self, gaddr: int) -> int:
         return gaddr // self.segment_words
+
+    def placement(self, gaddr: int) -> tuple[int, int]:
+        """``(device, slot)`` of the kernel that owns ``gaddr``."""
+        return divmod(self.owner_of(gaddr), self.ctx.kernels_per_device)
 
     def local_offset(self, gaddr: int) -> int:
         return gaddr % self.segment_words
@@ -104,8 +110,9 @@ class GlobalAddressSpace:
         """Build the sharded PgasState for all kernels.
 
         Returns a PgasState whose leaves are global arrays with leading
-        dim = num_kernels, sharded one-kernel-per-device; inside
-        ``ctx.spmd`` each kernel sees its own (segment_words,) slice.
+        dim = num_kernels, split over the devices (``kernels_per_device``
+        kernels each); inside ``ctx.spmd`` each kernel sees its own
+        (segment_words,) slice.
         """
         n = self.ctx.num_kernels
         proto = PgasState.make(self.segment_words, self.dtype)
@@ -138,9 +145,9 @@ class GlobalAddressSpace:
         return np.asarray(jax.device_get(state.segment)).reshape(-1)
 
     def spmd(self, fn, **kw):
-        """shard_map wrapper: ``fn(state) -> state`` written per-kernel;
+        """Per-kernel wrapper: ``fn(state) -> state`` written per-kernel;
         the global view gives every PgasState leaf a leading kernel dim
-        split over the kernel axes, removed inside."""
+        split over the devices, removed inside."""
         spec = P(self.ctx.axes)
 
         def inner(state):
@@ -148,5 +155,4 @@ class GlobalAddressSpace:
             out = fn(state)
             return jax.tree.map(lambda x: x[None], out)
 
-        return shard_map(inner, mesh=self.ctx.mesh, in_specs=spec,
-                             out_specs=spec, **kw)
+        return self.ctx.kernel_map(inner, spec, spec, **kw)

@@ -27,11 +27,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core import am
 from repro.core import handlers as hd
-from repro.core.state import PgasState, ShoalContext
+from repro.core.state import PgasState, ShoalContext, from_slot
 from repro.kernels.am_pack.ref import strided_indices
 
 _I_NWORDS = am.FIELDS.index("nwords")
@@ -50,6 +51,18 @@ def _pad_segment(segment: jnp.ndarray, packet_words: int) -> jnp.ndarray:
     without the address clip sliding the window."""
     return jnp.concatenate(
         [segment, jnp.zeros((packet_words,), segment.dtype)])
+
+
+def deliver_local(ctx: ShoalContext, pairs, x: jnp.ndarray) -> jnp.ndarray:
+    """In-device delivery between kernels on one device (libGalapagos'
+    software routing between kernels on one node): each destination of
+    ``pairs``, every one on its source's device, receives its source's
+    ``x``; every other kernel receives zeros, as from a ``ppermute``.  A
+    move between slots: no collective."""
+    src = np.full((ctx.num_kernels,), -1, np.int32)
+    for s, d in pairs:
+        src[d] = s % ctx.kernels_per_device
+    return from_slot(x, jnp.asarray(src)[ctx.my_id()])
 
 
 def egress(ctx: ShoalContext, state: PgasState, hdr: am.Header,

@@ -17,6 +17,11 @@ fused into a single int32 packet (:func:`repro.core.am.pack_packet`) so
 a whole AM crosses a link in ONE ``ppermute`` — the wire shape of the
 paper's GAScore, which parses a single AXIS stream, never two.
 
+Kernels on one device: a pattern's pairs whose two kernels share a
+device take the LOCAL path, a move between the device's slots that
+issues no collective (:func:`repro.core.gascore.deliver_local`); pairs
+across devices keep the ``ppermute``; a mixed pattern does both.
+
 Message-size segmentation: AMs whose payload exceeds the transport's
 ``max_packet_words`` are transparently split into sequence-numbered
 packets.  The paper hits this limit (9000-byte jumbo frames) in the
@@ -41,7 +46,10 @@ from repro.core import faults as flt
 from repro.core import gascore as gc
 from repro.core import handlers as hd
 from repro.core.state import (ERR_CRC, ERR_RETRY_EXHAUSTED,
-                              ERR_WAIT_UNDERFLOW, PgasState, ShoalContext)
+                              ERR_WAIT_UNDERFLOW, PgasState, ShoalContext,
+                              from_slot, slots_total)
+from repro.runtime.topology import split_local
+from repro.runtime.transport import LinkClass
 from repro.runtime.transport import is_lossy as _transport_is_lossy
 
 Pattern = list[tuple[int, int]]
@@ -80,6 +88,68 @@ def _dst_of(ctx: ShoalContext, pattern: Pattern):
     return table[ctx.my_id()]
 
 
+def _device_rounds(ctx: ShoalContext, remote: Pattern):
+    """Group pairs across devices into rounds of one ``ppermute`` each:
+    in a round every device sends to at most one device and receives
+    from at most one.  Returns ``[(device perm, pairs), ...]``."""
+    kpd = ctx.kernels_per_device
+    rounds: list[tuple[dict, dict, Pattern]] = []
+    for s, d in remote:
+        a, b = s // kpd, d // kpd
+        for to, frm, pairs in rounds:
+            if to.get(a, b) == b and frm.get(b, a) == a:
+                to[a], frm[b] = b, a
+                pairs.append((s, d))
+                break
+        else:
+            rounds.append(({a: b}, {b: a}, [(s, d)]))
+    return [(sorted(to.items()), pairs) for to, _, pairs in rounds]
+
+
+def _route(ctx: ShoalContext, pattern: Pattern, x: jnp.ndarray):
+    """Move ``x`` along ``pattern``: what each destination receives from
+    its source, zeros elsewhere.  With one kernel per device that is one
+    ``ppermute``.  With several, pairs on one device move between slots
+    (``local``), and pairs across devices go by rounds: each packet is
+    first moved into its destination's slot on its own device, then the
+    device's slot stack crosses the link in one ``ppermute`` (``wire``).
+    """
+    if ctx.kernels_per_device == 1:
+        with _lint.layer("wire"):
+            return lax.ppermute(x, ctx.axes, pattern)
+    local, remote = split_local(pattern, ctx.kernels_per_device)
+    kpd, me = ctx.kernels_per_device, ctx.my_id()
+    out = None
+    if local:
+        with _lint.layer("local"):
+            out = gc.deliver_local(ctx, local, x)
+    for perm, pairs in _device_rounds(ctx, remote):
+        stage = np.full((ctx.num_kernels,), -1, np.int32)
+        dst = np.zeros((ctx.num_kernels,), bool)
+        for s, d in pairs:
+            stage[s - s % kpd + d % kpd] = s % kpd
+            dst[d] = True
+        with _lint.layer("local"):
+            staged = from_slot(x, jnp.asarray(stage)[me])
+        with _lint.layer("wire"):
+            moved = lax.ppermute(staged, ctx.axes, perm)
+        out = moved if out is None else jnp.where(jnp.asarray(dst)[me],
+                                                  moved, out)
+    return out
+
+
+def _carried(ctx: ShoalContext, local: Pattern, remote: Pattern, *arrays):
+    """Count an exchange's packets and bytes by link class for the
+    recorder (:func:`repro.analysis.trace.carried`): every pair ships
+    each kernel's packet rows."""
+    arrays = [a for a in arrays if a is not None]
+    rows = arrays[0].shape[0] if arrays[0].ndim == 2 else 1
+    nbytes = sum(a.size * a.dtype.itemsize for a in arrays)
+    for link, pairs in ((LinkClass.LOCAL, local), (LinkClass.ICI, remote)):
+        if pairs:
+            _lint.carried(link.name, rows * len(pairs), nbytes * len(pairs))
+
+
 def _exchange(ctx: ShoalContext, pattern: Pattern, hdr: jnp.ndarray,
               payload: jnp.ndarray | None, extra: jnp.ndarray | None = None):
     """One link traversal: ship ``header ++ [extra ++] payload`` along
@@ -87,31 +157,39 @@ def _exchange(ctx: ShoalContext, pattern: Pattern, hdr: jnp.ndarray,
     not.  Header-only messages are already single packets.
 
     Returns ``(hdr, payload)`` — plus ``extra`` in the middle when an
-    extra section was given.  Pure-local patterns (src == dst for every
-    pair) short-circuit: no collective is issued, mirroring
-    libGalapagos' internal routing for same-node kernels.  Non-32-bit
-    payloads cannot bitcast onto the int32 wire and fall back to split
-    collectives.
+    extra section was given.  Patterns whose pairs all stay on one
+    device issue no collective, mirroring libGalapagos' internal
+    routing for same-node kernels: with one kernel per device those are
+    self-puts (src == dst) and return the packet as it is; with several
+    kernels per device each section moves between the device's slots
+    unpacked (:func:`repro.core.gascore.deliver_local`).  A mixed
+    pattern ships one fused packet over both paths (:func:`_route`).
+    Non-32-bit payloads cannot bitcast onto the int32 wire and fall back
+    to split sections.
     """
-    remote = [(s, d) for (s, d) in pattern if s != d]
+    local, remote = split_local(pattern, ctx.kernels_per_device)
+    _carried(ctx, local, remote, hdr, extra, payload)
     if not remote:
-        return (hdr, extra, payload) if extra is not None else (hdr, payload)
+        if ctx.kernels_per_device == 1:
+            return (hdr, extra, payload) if extra is not None else (hdr, payload)
+        with _lint.layer("local"):
+            hdr_r, extra_r, pay_r = (
+                None if a is None else gc.deliver_local(ctx, local, a)
+                for a in (hdr, extra, payload))
+        return (hdr_r, extra_r, pay_r) if extra is not None else (hdr_r, pay_r)
     if payload is None and extra is None:
-        with _lint.layer("wire"):
-            return lax.ppermute(hdr, ctx.axes, pattern), None
+        return _route(ctx, pattern, hdr), None
     if payload is not None and not am.wire_dtype_ok(payload.dtype):
-        with _lint.layer("wire"):
-            hdr_r = lax.ppermute(hdr, ctx.axes, pattern)
-            pay_r = lax.ppermute(payload, ctx.axes, pattern)
-            if extra is None:
-                return hdr_r, pay_r
-            return hdr_r, lax.ppermute(extra, ctx.axes, pattern), pay_r
+        hdr_r = _route(ctx, pattern, hdr)
+        pay_r = _route(ctx, pattern, payload)
+        if extra is None:
+            return hdr_r, pay_r
+        return hdr_r, _route(ctx, pattern, extra), pay_r
     n_extra = 0 if extra is None else extra.shape[-1]
     dtype = jnp.int32 if payload is None else payload.dtype
     with _lint.layer("egress"):
         pkt = am.pack_packet(hdr, payload, extra)
-    with _lint.layer("wire"):
-        pkt_r = lax.ppermute(pkt, ctx.axes, pattern)
+    pkt_r = _route(ctx, pattern, pkt)
     with _lint.layer("ingress"):
         out = am.unpack_packet(pkt_r, dtype, n_extra)
     if payload is None and extra is not None:
@@ -287,9 +365,12 @@ def _lossy_exchange(ctx: ShoalContext, state: PgasState, pattern: Pattern,
     """
     with _lint.layer("egress"):
         pkt = am.seal_packet(pkt)
-    remote = [(s, d) for (s, d) in pattern if s != d]
-    with _lint.layer("wire"):
-        pkt_r = lax.ppermute(pkt, ctx.axes, pattern) if remote else pkt
+    local, remote = split_local(pattern, ctx.kernels_per_device)
+    _carried(ctx, local, remote, pkt)
+    if remote or ctx.kernels_per_device > 1:
+        pkt_r = _route(ctx, pattern, pkt)
+    else:
+        pkt_r = pkt
     # the fault emulator stands in for the link: no layer of its own
     drop, dup, corrupt = _lossy_recv_probs(ctx, pattern)
     key = flt.fault_key(ctx.transport.faults, ctx.my_id(), token, epoch,
@@ -1119,11 +1200,17 @@ def barrier(ctx: ShoalContext, state: PgasState) -> PgasState:
     """Global barrier over all kernels (paper Sec. III: "barriers for
     synchronization").  A psum of a unit scalar is the dataflow barrier:
     no kernel's successor ops can be scheduled before every kernel's
-    contribution arrives.  The barrier epoch counts completions."""
+    contribution arrives.  Kernels that share a device add up their
+    arrivals inside it first, and only devices join the psum.  The
+    barrier epoch counts completions."""
     tag = _lint.emit("barrier", [])
     with _lint.scope(tag), _lint.layer("sync"):
+        one = jnp.ones((), jnp.int32)
+        if ctx.kernels_per_device > 1:
+            with _lint.layer("local"):
+                one = slots_total(one)
         with _lint.layer("wire"):
-            arrived = lax.psum(jnp.ones((), jnp.int32), ctx.axes)
+            arrived = lax.psum(one, ctx.axes)
         epoch = state.barrier_epoch + (arrived // arrived)  # data-dependent
         return gc.dataclasses_replace(state, barrier_epoch=epoch)
 

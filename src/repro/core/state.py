@@ -8,24 +8,41 @@ accounting.  All Shoal ops thread it explicitly (dataflow has no mutable
 runtime).
 
 ``ShoalContext`` is the trace-time configuration: which mesh axes
-enumerate kernels, the transport (acked/async + packet limit), and the
-handler table.  It is the analogue of a linked Shoal library instance.
+enumerate kernels, how many kernels each device holds, the transport
+(acked/async + packet limit), and the handler table.  It is the analogue
+of a linked Shoal library instance.
+
+Several kernels on one device (the paper's several kernels on one node)
+run as a ``vmap`` over the device's slots inside the ``shard_map``
+(:meth:`ShoalContext.kernel_map`): each kernel still sees only its own
+``PgasState``, so its segment, credits, ack ledger and counters are its
+own, and its ID is ``device * kernels_per_device + slot``.  Data moves
+between slots only through the two slot primitives at the end of this
+module, whose batching rules see the device's whole slot stack: nothing
+crosses a link.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.custom_batching import custom_vmap
 
 from jax import shard_map
 
 from repro.core import handlers as hd
 from repro.runtime.transport import Transport, TCP
+
+# the slot of the kernel being traced, innermost kernel_map last: a
+# value of the slot vmap, since ``axis_index`` of a vmap axis cannot
+# mix with mesh-varying values inside loops
+_SLOTS: list = []
 
 
 @jax.tree_util.register_dataclass
@@ -218,10 +235,12 @@ class ShoalContext:
 
     Attributes:
       mesh: the device mesh (cluster).
-      axes: mesh axis name(s) that enumerate kernels, row-major.
+      axes: mesh axis name(s) that enumerate devices, row-major.
       transport: delivery semantics + packet limit (TCP/UDP analogue).
       handlers: the frozen handler table.
       segment_words: words in each kernel's segment.
+      kernels_per_device: Shoal kernels on each device (slots); kernel
+        ``k`` lives on device ``k // kernels_per_device``.
     """
 
     mesh: Any
@@ -229,14 +248,25 @@ class ShoalContext:
     transport: Transport = TCP
     handlers: hd.HandlerTable = dataclasses.field(default_factory=lambda: hd.DEFAULT_TABLE)
     segment_words: int = 4096
+    kernels_per_device: int = 1
+
+    @property
+    def num_devices(self) -> int:
+        return math.prod(self.mesh.shape[a] for a in self.axes)
 
     @property
     def num_kernels(self) -> int:
-        return math.prod(self.mesh.shape[a] for a in self.axes)
+        return self.num_devices * self.kernels_per_device
 
     def my_id(self):
-        """Flattened kernel ID of the executing device (inside shard_map)."""
-        return lax.axis_index(self.axes)
+        """Kernel ID of the executing kernel (inside :meth:`kernel_map`)."""
+        if self.kernels_per_device == 1:
+            return lax.axis_index(self.axes)
+        if not _SLOTS:
+            raise RuntimeError("my_id() of kernels that share a device is "
+                               "defined inside ShoalContext.kernel_map")
+        return lax.axis_index(self.axes) * self.kernels_per_device \
+            + _SLOTS[-1]
 
     def make_state(self, dtype=jnp.float32) -> PgasState:
         return PgasState.make(self.segment_words, dtype)
@@ -257,16 +287,73 @@ class ShoalContext:
 
         return ReplyMailbox(self)
 
+    def kernel_map(self, fn, in_specs, out_specs, **shard_map_kwargs):
+        """``shard_map`` of a per-kernel ``fn`` over the mesh: ``fn``
+        sees one kernel's block (leading dim 1) of each argument, as
+        under a plain ``shard_map`` with one kernel per device.  Where
+        devices hold several kernels, every argument and result is split
+        over the kernels, and ``fn`` runs once per slot under a
+        ``vmap``."""
+        if self.kernels_per_device == 1:
+            return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, **shard_map_kwargs)
+
+        def one_kernel(slot, *blocks):
+            _SLOTS.append(slot)
+            try:
+                out = fn(*jax.tree.map(lambda x: x[None], blocks))
+            finally:
+                _SLOTS.pop()
+            return jax.tree.map(lambda x: x[0], out)
+
+        @functools.wraps(fn)
+        def per_device(*args):
+            slots = jnp.arange(self.kernels_per_device, dtype=jnp.int32)
+            return jax.vmap(one_kernel)(slots, *args)
+
+        return shard_map(per_device, mesh=self.mesh, in_specs=in_specs,
+                         out_specs=out_specs, **shard_map_kwargs)
+
     def spmd(self, fn, state_spec=None, **shard_map_kwargs):
-        """Wrap ``fn`` in shard_map over the kernel axes.
+        """Wrap ``fn`` in :meth:`kernel_map` over the kernel axes.
 
         Every PgasState leaf is per-kernel, i.e. sharded over the
-        (flattened) kernel axes on its leading dim when viewed globally;
+        (flattened) device axes on its leading dim when viewed globally;
         we use rank-preserving specs: leading dim split over axes.
         """
         from jax.sharding import PartitionSpec as P
 
         spec = P(self.axes) if state_spec is None else state_spec
-        return shard_map(
-            fn, mesh=self.mesh, in_specs=spec, out_specs=spec, **shard_map_kwargs
-        )
+        return self.kernel_map(fn, spec, spec, **shard_map_kwargs)
+
+
+# -- the slot primitives ------------------------------------------------------
+# Outside the slot ``vmap`` a kernel is alone on its device; under it,
+# their batching rules see every kernel of the device at once.
+
+@custom_vmap
+def from_slot(x, src):
+    """What this kernel receives from slot ``src`` of its device (zeros
+    where ``src`` is -1): an in-device move, no collective."""
+    return jnp.where(src == 0, x, jnp.zeros_like(x))
+
+
+@from_slot.def_vmap
+def _from_slot_stack(axis_size, in_batched, x, src):
+    x = x if in_batched[0] else jnp.broadcast_to(x, (axis_size, *x.shape))
+    src = src if in_batched[1] else jnp.broadcast_to(src, (axis_size,))
+    got = jnp.take(x, jnp.clip(src, 0, axis_size - 1), axis=0)
+    keep = (src >= 0).reshape((axis_size,) + (1,) * (x.ndim - 1))
+    return jnp.where(keep, got, jnp.zeros_like(got)), True
+
+
+@custom_vmap
+def slots_total(x):
+    """The sum of ``x`` over the kernels of this device."""
+    return x
+
+
+@slots_total.def_vmap
+def _slots_total_stack(axis_size, in_batched, x):
+    total = x.sum(axis=0) if in_batched[0] else x * axis_size
+    return jnp.broadcast_to(total, (axis_size, *total.shape)), True

@@ -6,6 +6,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
 from repro.kernels.jacobi.jacobi import jacobi_step_pallas
 from repro.kernels.jacobi.ref import jacobi_step_ref
@@ -33,12 +34,31 @@ def jacobi_band_step(band: jnp.ndarray, top: jnp.ndarray, bottom: jnp.ndarray,
                      row0, *, m_total: int,
                      interpret: bool = False) -> jnp.ndarray:
     """One Pallas iteration over a row band of an (m_total, N) grid, given
-    the halo rows just above/below it and its first global row."""
+    the halo rows just above/below it and its first global row.  Under
+    ``vmap`` (several Shoal kernels on one chip) the bands run as one
+    call over their stack."""
     m, n = band.shape
-    return jacobi_step_pallas(
-        band, top, bottom, row0, m_total=m_total,
-        block_rows=_pick_block_rows(m, n, band.dtype.itemsize),
-        interpret=interpret)
+    return _band_stepper(m_total, _pick_block_rows(m, n, band.dtype.itemsize),
+                         interpret)(band, top, bottom, row0)
+
+
+@functools.cache
+def _band_stepper(m_total: int, block_rows: int, interpret: bool):
+    kw = dict(m_total=m_total, block_rows=block_rows, interpret=interpret)
+
+    @custom_vmap
+    def step(band, top, bottom, row0):
+        return jacobi_step_pallas(band, top, bottom, row0, **kw)
+
+    @step.def_vmap
+    def _stack(axis_size, in_batched, *args):
+        # the row0 of each band is prefetched: batching it would make
+        # the generic rule loop over the bands, one call each
+        args = [a if batched else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, batched in zip(map(jnp.asarray, args), in_batched)]
+        return jacobi_step_pallas(*args, **kw), True
+
+    return step
 
 
 def jacobi_step(x: jnp.ndarray, *, use_pallas: bool = True,

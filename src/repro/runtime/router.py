@@ -2,16 +2,17 @@
 
 libGalapagos routes packets between local kernels in software and hands
 off-node traffic to the network driver.  The XLA analogue: traffic whose
-source and destination are the same chip never becomes a collective
-(LOCAL short-circuit); intra-pod traffic lowers to collective-permute on
-ICI; inter-pod traffic crosses the DCN ("pod") axis.
+source and destination kernels live on the same chip never becomes a
+collective (LOCAL: a move inside the chip); intra-pod traffic lowers to
+collective-permute on ICI; inter-pod traffic crosses the DCN ("pod")
+axis.
 """
 
 from __future__ import annotations
 
 import dataclasses
 
-from repro.runtime.topology import ClusterSpec, kernel_coords, pod_of
+from repro.runtime.topology import ClusterSpec, device_of, kernel_coords, pod_of
 from repro.runtime.transport import LinkClass
 
 
@@ -21,7 +22,8 @@ class Router:
 
     def classify(self, src: int, dst: int) -> LinkClass:
         """Which link class a src->dst AM traverses."""
-        if src == dst:
+        kpd = self.spec.kernels_per_device
+        if device_of(src, kpd) == device_of(dst, kpd):
             return LinkClass.LOCAL
         if pod_of(self.spec, src) != pod_of(self.spec, dst):
             return LinkClass.DCN
@@ -41,4 +43,5 @@ class Router:
         return kernel_coords(self.spec, kernel_id)
 
     def is_pure_local(self, pattern: list[tuple[int, int]]) -> bool:
-        return all(s == d for s, d in pattern)
+        return all(self.classify(s, d) == LinkClass.LOCAL
+                   for s, d in pattern)

@@ -3,8 +3,12 @@
 Galapagos turns user configuration files into a deployed cluster of
 CPU/FPGA nodes, each holding one or more kernels.  Here a "cluster" is a
 JAX device mesh: pods (DCN-connected) x chips (ICI-connected), and a
-"kernel" is one per-device program instance under ``shard_map``.  The
-kernel ID of the paper is the flattened mesh index.
+"kernel" is one program instance under ``shard_map``.  A device holds
+``kernels_per_device`` kernels (one by default), in slots; the kernel ID
+of the paper is ``device * kernels_per_device + slot``, with ``device``
+the flattened mesh index.  Two kernels on one device share no
+collective: packets between them take the LOCAL path, a move inside the
+device (libGalapagos' software routing between kernels on one node).
 """
 
 from __future__ import annotations
@@ -27,12 +31,15 @@ class ClusterSpec:
       kernel_axes: the axes over which Shoal kernels are enumerated.  By
         default all axes: every device in the mesh is one kernel.
       pod_axis: name of the inter-pod (DCN) axis, or None for single-pod.
+      kernels_per_device: Shoal kernels on each device of the kernel
+        axes (the paper's kernels on one node).
     """
 
     mesh_shape: tuple[int, ...]
     axis_names: tuple[str, ...]
     kernel_axes: tuple[str, ...] | None = None
     pod_axis: str | None = None
+    kernels_per_device: int = 1
 
     def __post_init__(self):
         if len(self.mesh_shape) != len(self.axis_names):
@@ -44,6 +51,8 @@ class ClusterSpec:
                 raise ValueError(f"kernel axis {ax!r} not in {self.axis_names}")
         if self.pod_axis is not None and self.pod_axis not in self.axis_names:
             raise ValueError(f"pod axis {self.pod_axis!r} not in {self.axis_names}")
+        if self.kernels_per_device < 1:
+            raise ValueError("kernels_per_device must be at least 1")
 
     @property
     def num_devices(self) -> int:
@@ -51,7 +60,7 @@ class ClusterSpec:
 
     @property
     def num_kernels(self) -> int:
-        n = 1
+        n = self.kernels_per_device
         for ax, size in zip(self.axis_names, self.mesh_shape):
             if ax in self.kernel_axes:
                 n *= size
@@ -81,11 +90,29 @@ def make_cpu_mesh(n: int | None = None, names: tuple[str, ...] = ("kernel",)):
     return make_mesh((n,), names)
 
 
+def device_of(kernel_id: int, kernels_per_device: int = 1) -> int:
+    """The flattened index of the device that holds kernel ``kernel_id``."""
+    return kernel_id // kernels_per_device
+
+
+def split_local(pattern: Sequence[tuple[int, int]],
+                kernels_per_device: int = 1):
+    """``(local, remote)``: the pairs of ``pattern`` whose two kernels
+    share a device (the LOCAL path, no collective), and the others."""
+    local, remote = [], []
+    for s, d in pattern:
+        same = (device_of(s, kernels_per_device)
+                == device_of(d, kernels_per_device))
+        (local if same else remote).append((s, d))
+    return local, remote
+
+
 def kernel_coords(spec: ClusterSpec, kernel_id: int) -> dict[str, int]:
-    """kernel ID -> per-axis coordinates (row-major over kernel_axes)."""
+    """kernel ID -> per-axis coordinates of its device (row-major over
+    kernel_axes)."""
     sizes = [spec.axis_size(a) for a in spec.kernel_axes]
     coords: dict[str, int] = {}
-    rem = kernel_id
+    rem = device_of(kernel_id, spec.kernels_per_device)
     for ax, size in zip(reversed(spec.kernel_axes), reversed(sizes)):
         coords[ax] = rem % size
         rem //= size

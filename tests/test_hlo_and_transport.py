@@ -138,6 +138,31 @@ def test_router_link_classes():
     assert r.is_pure_local([(0, 0), (1, 1)])
 
 
+def test_colocated_placement():
+    """Kernels outnumber devices: kernel k lives in slot k % 4 of device
+    k // 4; pairs on one device are LOCAL, the others ICI or DCN."""
+    from repro.core.address_space import GlobalAddressSpace
+    from repro.core.state import ShoalContext
+    from repro.runtime.topology import kernel_coords, make_cpu_mesh, split_local
+
+    spec = ClusterSpec((2, 2), ("pod", "chip"), pod_axis="pod",
+                       kernels_per_device=4)
+    assert spec.num_devices == 4 and spec.num_kernels == 16
+    assert kernel_coords(spec, 13) == {"pod": 1, "chip": 1}
+    r = Router(spec)
+    assert r.classify(4, 7) == LinkClass.LOCAL       # both on device 1
+    assert r.classify(3, 4) == LinkClass.ICI         # devices 0 and 1
+    assert r.classify(7, 8) == LinkClass.DCN         # pods 0 and 1
+    assert r.is_pure_local([(0, 3), (5, 4)])
+    assert split_local([(0, 3), (3, 4), (9, 9)], 4) == ([(0, 3), (9, 9)],
+                                                       [(3, 4)])
+    ctx = ShoalContext(mesh=make_cpu_mesh(1, ("kernel",)), axes=("kernel",),
+                       segment_words=128, kernels_per_device=8)
+    gas = GlobalAddressSpace(ctx)
+    assert ctx.num_kernels == 8
+    assert gas.placement(gas.global_addr(5, 7)) == (0, 5)
+
+
 def test_latency_model_ordering():
     """The paper's qualitative results: async (UDP) < acked (TCP), and
     LOCAL < ICI < DCN, and latency grows with payload."""

@@ -85,11 +85,12 @@ _SHAPE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = [a-z]\w*\[([\d,]*)\]",
                     re.M)
 
 
-def _jacobi_app_hlo(monkeypatch, kernel_mesh, use_pallas, kernels=4):
+def _jacobi_app_hlo(monkeypatch, kernel_mesh, use_pallas, kernels=4,
+                    chips=None):
     import repro.apps.jacobi as jacobi_app
     monkeypatch.setattr(jacobi_app, "make_cpu_mesh", kernel_mesh)
     app = jacobi_app.JacobiApp(n=4096, kernels=kernels, iters=2,
-                               use_pallas=use_pallas)
+                               use_pallas=use_pallas, chips=chips)
     blocks = jax.ShapeDtypeStruct(
         (kernels, app.rows, app.n), jnp.float32,
         sharding=NamedSharding(app.mesh, P(("kernel",))))
@@ -151,6 +152,26 @@ def test_jacobi_app_1_kernel_stencil_reads_band_once(kernel_mesh,
     reads it as its only band-sized operand, with no shifted copies."""
     hlo = _jacobi_app_hlo(monkeypatch, kernel_mesh, True, kernels=1)
     _assert_stencil_reads_band_once(hlo, 4096, 4096)
+
+
+def test_jacobi_app_8_kernels_on_1_chip_compiles(kernel_mesh, monkeypatch):
+    """The paper's 8 kernels on one node, on one chip: no collective is
+    left (the halos take the LOCAL path, the barrier's psum over one
+    device goes), the in-chip moves carry the ``local`` layer, and the
+    stencil is one ``jacobi_step_pallas`` call over the 8 stacked
+    bands."""
+    from repro.launch.hlo_analysis import op_layers, parse_collectives
+
+    hlo = _jacobi_app_hlo(monkeypatch, kernel_mesh, True, kernels=8,
+                          chips=1)
+    assert parse_collectives(hlo).ops == {}
+    layers, instrs = op_layers(hlo), _instructions(hlo)
+    assert "local" in set(layers.values())
+    calls = [k for k, (op, rest) in instrs.items()
+             if op == "custom-call" and "tpu_custom_call" in rest]
+    assert len(calls) == 1 and calls[0].startswith("jacobi_step_pallas")
+    assert layers[calls[0]] == "compute"
+    assert re.search(rf"%{re.escape(calls[0])} = f32\[8,512,4096\]", hlo)
 
 
 def test_layer_scopes_change_no_instruction(kernel_mesh, monkeypatch):

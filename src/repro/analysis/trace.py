@@ -142,11 +142,13 @@ class CommEvent:
 
 class Recorder:
     """Collects :class:`CommEvent`s while installed (see :func:`record`),
-    and the packets and bytes each link class carried (:func:`carried`)."""
+    the packets and bytes each link class carried (:func:`carried`), and
+    how each packet stack was landed (:func:`landed`)."""
 
     def __init__(self) -> None:
         self.events: list[CommEvent] = []
         self.links: dict[str, dict[str, int]] = {}
+        self.landings: list[tuple[str, int]] = []
 
     def next_seq(self) -> int:
         return len(self.events)
@@ -233,6 +235,20 @@ def carried(link: str, packets: int, nbytes: int) -> None:
             link, {"packets": 0, "bytes": 0})
         tally["packets"] += packets
         tally["bytes"] += nbytes
+
+
+LANDINGS = ("one_pass", "scan")
+
+
+def landed(path: str, rows: int) -> None:
+    """Record one GAScore landing of a packet stack while a
+    :func:`record` block is active: ``path`` is ``one_pass`` (static
+    writes per item) or ``scan`` (a ``lax.scan`` over the rows), and
+    ``rows`` the stack's height."""
+    if path not in LANDINGS:
+        raise ValueError(f"unknown landing {path!r}; one of {LANDINGS}")
+    if _RECORDERS:
+        _RECORDERS[-1].landings.append((path, int(rows)))
 
 
 def scope(tag: str):

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.analysis import trace as _lint
 from repro.core import am
 from repro.core import handlers as hd
 from repro.core.state import PgasState, ShoalContext, from_slot
@@ -303,23 +304,37 @@ def ingress_medium(state: PgasState, hdr: am.Header, payload: jnp.ndarray,
     return state, delivered
 
 
-def ingress_short(ctx: ShoalContext, state: PgasState, hdr: am.Header) -> PgasState:
+def ingress_short(ctx: ShoalContext, state: PgasState, hdr: am.Header,
+                  handler: int | None = None) -> PgasState:
     """Short ingress: signaling.  The handler runs on a one-word region of
     the credit file at ``token`` with ``dst_addr`` as its argument, so
     H_ADD implements counting semaphores (the paper's primary Short use).
     Reply messages (FLAG_REPLY) bump the credit counter directly: reply
-    management is absorbed into the runtime (paper Sec. III-A)."""
+    management is absorbed into the runtime (paper Sec. III-A).
+
+    ``handler`` is the sender's built-in handler where it is static (the
+    runtime's own H_ADD counts): it then runs over the whole credit file
+    and the token's word is selected, so neither a switch nor a
+    gather/scatter at the traced token is compiled (under the slot
+    ``vmap`` those become loops)."""
     is_short = hdr.msg_class == am.SHORT
     is_reply = is_short & hdr.flag(am.FLAG_REPLY)
     is_user = is_short & ~hdr.flag(am.FLAG_REPLY)
 
     token = jnp.clip(hdr.token, 0, hd.NUM_TOKENS - 1)
+    arg = hdr.dst_addr.astype(state.credits.dtype)
+    if handler is not None:
+        assert 0 <= handler < hd.NUM_BUILTIN, handler
+        hit = lax.iota(jnp.int32, hd.NUM_TOKENS) == token
+        credits = state.credits + (hit & is_reply).astype(jnp.int32)
+        new = ctx.handlers.fn(handler)(credits, arg)
+        return dataclasses_replace(
+            state, credits=jnp.where(hit & is_user, new, credits))
     # replies: credits[token] += 1
     credits = state.credits.at[token].add(is_reply.astype(jnp.int32))
     # user shorts: handler over credits[token] with arg payload [dst_addr]
     region = lax.dynamic_slice(credits, (token,), (1,))
-    arg = hdr.dst_addr.astype(credits.dtype).reshape(1)
-    new_region = ctx.handlers.dispatch(hdr.handler, region, arg)
+    new_region = ctx.handlers.dispatch(hdr.handler, region, arg.reshape(1))
     new_region = jnp.where(is_user, new_region, region)
     credits = lax.dynamic_update_slice(credits, new_region, (token,))
     return dataclasses_replace(state, credits=credits)
@@ -327,8 +342,7 @@ def ingress_short(ctx: ShoalContext, state: PgasState, hdr: am.Header) -> PgasSt
 
 def ingress_stack(ctx: ShoalContext, state: PgasState, hdr_rows: jnp.ndarray,
                   pay_rows: jnp.ndarray, packet_words: int) -> PgasState:
-    """Mixed-class scanned ingress for a coalesced packet stack (the
-    actor-mailbox flush path, :mod:`repro.actors`).
+    """Mixed-class scanned ingress for a coalesced packet stack.
 
     Unlike :func:`ingress_long_batch`, whose rows are segments of ONE
     message, each row here is an independent tiny AM with its own class,
@@ -338,7 +352,15 @@ def ingress_stack(ctx: ShoalContext, state: PgasState, hdr_rows: jnp.ndarray,
     class-gated per row, so one ``lax.scan`` absorbs a stack that mixes
     them freely — the dataflow analogue of the GAScore draining a burst
     of aggregated messages off one AXIS stream.
+
+    Its callers are the actor-mailbox flush (:mod:`repro.actors`), whose
+    stacks mix classes, and the ``put_long_multi`` stacks that
+    :func:`ingress_long_stack` cannot land exactly: a traced or
+    out-of-segment destination, a registered handler, or items that
+    alias under a waiver.
     """
+    _lint.landed("scan", hdr_rows.shape[0])
+
     def body(st, row):
         h, p = row
         hd_ = am.decode(h)
@@ -352,6 +374,62 @@ def ingress_stack(ctx: ShoalContext, state: PgasState, hdr_rows: jnp.ndarray,
     state, _ = lax.scan(body, state, (hdr_rows, pay_rows))
     return dataclasses_replace(state,
                                segment=state.segment[:ctx.segment_words])
+
+
+def ingress_long_stack(ctx: ShoalContext, state: PgasState,
+                       hdr_rows: jnp.ndarray, pay_rows: jnp.ndarray,
+                       blocks, handler: int,
+                       packet_words: int) -> PgasState:
+    """Land a Long-only packet stack in one pass, with no scan: the
+    ``put_long_multi`` ingress when the sender's plan is static.
+
+    ``blocks`` is that plan, one ``(row0, nseg, dst_addr, nwords)`` per
+    item: the item's rows ``[row0, row0 + nseg)`` of the stack and its
+    trace-time destination ``[dst_addr, dst_addr + nwords)`` inside the
+    segment; ``handler`` is the items' built-in handler.  The rows' headers
+    still decide what lands: a row lands only if it is LONG (a
+    non-sender's rows are NOPs), and only its first ``nwords`` lanes.
+    Rows are full but an item's last (:func:`egress_batch` builds them
+    so), so an item's lanes flatten into its region with static slices;
+    its handler runs once over the region, which is written with one
+    masked update at the static offset.  Built-in handlers are
+    elementwise, so this equals :func:`ingress_stack`'s row-by-row
+    landing bit for bit.
+
+    The ack lanes of :func:`ingress_ack_lanes` become one sum each over
+    the rows, per token (integer additions commute, so the row order is
+    immaterial; a sum of one-hot rows, where a scatter-add under the
+    slot ``vmap`` would compile to a loop), and ``rx_words`` one sum.
+    The stack holds no SHORT row, so the Short datapath is not built.
+    """
+    _lint.landed("one_pass", hdr_rows.shape[0])
+    hdr = am.Header(*(hdr_rows[:, i] for i in range(am.HDR_WORDS)))
+    live = hdr.msg_class == am.LONG
+    segment = state.segment
+    for row0, nseg, dst_addr, nwords in blocks:
+        rows = slice(row0, row0 + nseg)
+        lanes = lax.broadcasted_iota(jnp.int32, (nseg, packet_words), 1)
+        mask = (lanes < hdr.nwords[rows, None]) & live[rows, None]
+        mask = mask.reshape(-1)[:nwords]
+        flat = pay_rows[rows].reshape(-1)[:nwords]
+        region = segment[dst_addr:dst_addr + nwords]
+        new = ctx.handlers.fn(handler)(region, flat)
+        segment = lax.dynamic_update_slice(
+            segment, jnp.where(mask, new, region), (dst_addr,))
+    tok, defer, pb_tok, pb = _ack_lanes(hdr)
+    return dataclasses_replace(
+        state, segment=segment,
+        rx_words=state.rx_words + jnp.sum(jnp.where(live, hdr.nwords, 0)),
+        deferred_acks=state.deferred_acks + _per_token(tok, defer),
+        credits=state.credits + _per_token(pb_tok, pb))
+
+
+def _per_token(tok: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
+    """``out[t]`` = the sum of ``counts`` over the rows whose token is
+    ``t``: a scatter-add over the credit file, as a sum."""
+    hit = tok[:, None] == lax.iota(jnp.int32, hd.NUM_TOKENS)[None, :]
+    return jnp.sum(jnp.where(hit, counts[:, None], 0), axis=0,
+                   dtype=jnp.int32)
 
 
 def _serve_get_row(ctx: ShoalContext, seg_p: jnp.ndarray, hdr: am.Header,
@@ -429,18 +507,24 @@ def ingress_ack_lanes(state: PgasState, hdr: am.Header) -> PgasState:
       ``pb_token`` from the sender's ledger — grant them:
       ``credits[pb_token] += pb_count``.
     """
+    tok, defer, pb_tok, pb = _ack_lanes(hdr)
+    return dataclasses_replace(
+        state, deferred_acks=state.deferred_acks.at[tok].add(defer),
+        credits=state.credits.at[pb_tok].add(pb))
+
+
+def _ack_lanes(hdr: am.Header):
+    """``(token, deferred, pb_token, granted)``: the ledger slot and the
+    acks a packet ledgers there, and the credit slot and the acks it
+    grants there (see :func:`ingress_ack_lanes`)."""
     live = hdr.msg_class != am.NOP
     defer = live & hdr.flag(am.FLAG_DEFER_ACK) \
         & ~hdr.flag(am.FLAG_ASYNC) & ~hdr.flag(am.FLAG_REPLY)
-    tok = jnp.clip(hdr.token, 0, hd.NUM_TOKENS - 1)
-    deferred = state.deferred_acks.at[tok].add(defer.astype(jnp.int32))
-
     carry = live & hdr.flag(am.FLAG_PIGGYBACK)
-    pb_tok = jnp.clip(hdr.pb_token, 0, hd.NUM_TOKENS - 1)
-    credits = state.credits.at[pb_tok].add(
-        jnp.where(carry, hdr.pb_count, 0).astype(jnp.int32))
-    return dataclasses_replace(state, deferred_acks=deferred,
-                               credits=credits)
+    return (jnp.clip(hdr.token, 0, hd.NUM_TOKENS - 1),
+            defer.astype(jnp.int32),
+            jnp.clip(hdr.pb_token, 0, hd.NUM_TOKENS - 1),
+            jnp.where(carry, hdr.pb_count, 0).astype(jnp.int32))
 
 
 def ingress_reply(state: PgasState, hdr: am.Header) -> PgasState:

@@ -66,6 +66,11 @@ class HandlerTable:
     def names(self) -> Sequence[str]:
         return [n for n, _ in self._entries]
 
+    def fn(self, handler_id: int) -> HandlerFn:
+        """The function of a static handler ID: a trace-time lookup, so
+        the compiled program holds no switch."""
+        return self._entries[handler_id][1]
+
     def dispatch(self, handler_id, region: jnp.ndarray, payload: jnp.ndarray):
         """Run handler ``handler_id`` on (region, payload) -> new region.
 

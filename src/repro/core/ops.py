@@ -738,7 +738,8 @@ def _counted_group_reply(ctx: ShoalContext, state: PgasState, union: Pattern,
         hdr = _mask_nonparticipants(ctx, rev, hdr)
     hdr_back, _ = _exchange(ctx, rev, hdr, None)
     with _lint.layer("ingress"):
-        return gc.ingress_short(ctx, state, am.decode(hdr_back))
+        return gc.ingress_short(ctx, state, am.decode(hdr_back),
+                                handler=hd.H_ADD)
 
 
 def put_long_multi(ctx: ShoalContext, state: PgasState, items, *,
@@ -752,11 +753,16 @@ def put_long_multi(ctx: ShoalContext, state: PgasState, items, *,
     Patterns whose source AND destination sets are disjoint form a valid
     union permutation: their per-destination ``(nseg, HDR+W)`` packet
     stacks concatenate and the whole group crosses the links as ONE
-    ``ppermute``, absorbed by the scanned mixed-class
-    :func:`repro.core.gascore.ingress_stack`.  Patterns that share a
-    source or destination (Jacobi's up+down halo pair) cannot legally
-    merge and land in separate groups — see
-    :func:`group_disjoint_patterns`.
+    ``ppermute``.  Patterns that share a source or destination (Jacobi's
+    up+down halo pair) cannot legally merge and land in separate groups
+    — see :func:`group_disjoint_patterns`.
+
+    A group's stack lands in one pass
+    (:func:`repro.core.gascore.ingress_long_stack`: one masked write per
+    item at its static ``dst_addr``) when every item's destination is a
+    trace-time constant inside the segment, the handler is a static
+    built-in, and no items alias under a waiver.  Otherwise the scanned
+    :func:`repro.core.gascore.ingress_stack` lands it row by row.
 
     Ack accounting: one credit per item, on that item's token
     (``tokens`` gives per-item tokens; default all ``token``).  On the
@@ -829,13 +835,18 @@ def put_long_multi(ctx: ShoalContext, state: PgasState, items, *,
     groups = group_disjoint_patterns([p for _, p, _, _ in parsed])
     acked = ctx.transport.acked and not asynchronous
     mtu = ctx.transport.max_packet_words
+    h_static = _lint.static_int(handler)
+    # the stack lands in one pass only where its plan is static and the
+    # scan's row order is not what the caller relies on
+    one_pass = (alias is None and h_static is not None
+                and 0 <= h_static < hd.NUM_BUILTIN)
     for gi, grp in enumerate(groups):
         # one packet width for the whole group so stacks concatenate;
         # re-planning every item at this width keeps egress's pad +
         # reshape exact (all rows but an item's last are full)
         W = min(mtu, max(parsed[i][3] for i in grp))
         group_tag = None
-        hdr_rows, pay_rows, union = [], [], []
+        hdr_rows, pay_rows, union, blocks, row0 = [], [], [], [], 0
         for i in grp:
             payload, pat, dst_addr, nw = parsed[i]
             tag = _lint.emit(
@@ -853,6 +864,8 @@ def put_long_multi(ctx: ShoalContext, state: PgasState, items, *,
             union.extend(pat)
             segs = _segments(nw, W)
             nseg = len(segs)
+            blocks.append((row0, nseg, ivs[i].start, nw))
+            row0 += nseg
             offs = jnp.asarray([o for o, _ in segs], jnp.int32)
             ws = jnp.asarray([w for _, w in segs], jnp.int32)
             with _lint.scope(tag), _lint.layer("egress"):
@@ -882,7 +895,13 @@ def put_long_multi(ctx: ShoalContext, state: PgasState, items, *,
                 pay_all = jnp.concatenate(pay_rows, axis=0)
             hdr_r, pay_r = _exchange(ctx, union, hdr_all, pay_all)
             with _lint.layer("ingress"):
-                state = gc.ingress_stack(ctx, state, hdr_r, pay_r, W)
+                if one_pass and all(
+                        a is not None and 0 <= a <= ctx.segment_words - nw
+                        for _, _, a, nw in blocks):
+                    state = gc.ingress_long_stack(ctx, state, hdr_r, pay_r,
+                                                  blocks, h_static, W)
+                else:
+                    state = gc.ingress_stack(ctx, state, hdr_r, pay_r, W)
             if acked and not defer_ack:
                 if reply_via is not None:
                     for i in grp:
@@ -930,7 +949,8 @@ def drain_deferred_acks(ctx: ShoalContext, state: PgasState,
         state = gc.dataclasses_replace(state, deferred_acks=ledger)
         hdr_r, _ = _exchange(ctx, pattern, hdr, None)
         with _lint.layer("ingress"):
-            return gc.ingress_short(ctx, state, am.decode(hdr_r))
+            return gc.ingress_short(ctx, state, am.decode(hdr_r),
+                                    handler=hd.H_ADD)
 
 
 def _strides_may_overlap(stride, blk_words: int, nblocks: int) -> bool:

@@ -125,6 +125,15 @@ def _assert_stencil_reads_band_once(hlo, rows, n):
     assert not views, views
 
 
+def _assert_ingress_has_no_loop(layers, instrs):
+    """The halo stacks land in one pass: the ``ingress`` layer holds no
+    ``while`` (a scan over packet rows) and no ``conditional`` (a
+    handler switch), though it holds the halo writes."""
+    ingress = [op for k, (op, _) in instrs.items() if layers[k] == "ingress"]
+    assert "dynamic-update-slice" in ingress
+    assert "while" not in ingress and "conditional" not in ingress
+
+
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_jacobi_app_4_kernels_compiles(kernel_mesh, monkeypatch, use_pallas):
     from repro.launch.hlo_analysis import op_layers
@@ -137,8 +146,7 @@ def test_jacobi_app_4_kernels_compiles(kernel_mesh, monkeypatch, use_pallas):
     permutes = [k for k, (op, _) in instrs.items()
                 if op.startswith("collective-permute")]
     assert permutes and all(layers[k] == "wire" for k in permutes)
-    assert any(op == "while" and layers[k] == "ingress"
-               for k, (op, _) in instrs.items())
+    _assert_ingress_has_no_loop(layers, instrs)
     if use_pallas:
         calls = [(k, rest) for k, (op, rest) in instrs.items()
                  if op == "custom-call" and "tpu_custom_call" in rest]
@@ -172,6 +180,23 @@ def test_jacobi_app_8_kernels_on_1_chip_compiles(kernel_mesh, monkeypatch):
     assert len(calls) == 1 and calls[0].startswith("jacobi_step_pallas")
     assert layers[calls[0]] == "compute"
     assert re.search(rf"%{re.escape(calls[0])} = f32\[8,512,4096\]", hlo)
+    _assert_ingress_has_no_loop(layers, instrs)
+
+
+def test_jacobi_app_1_kernel_module_unchanged(kernel_mesh, monkeypatch):
+    """One kernel sends no halo, so no AM path reaches its module: it is
+    instruction for instruction the recorded one (metadata aside, and
+    the Pallas kernel's serialized body, which carries source paths)."""
+    def stripped(hlo):
+        return [re.sub(r'"body":"[^"]*"', '"body":""',
+                       re.sub(r",? metadata=\{[^}]*\}", "", line)).strip()
+                for line in hlo.splitlines() if _INSTR.match(line)]
+
+    hlo = _jacobi_app_hlo(monkeypatch, kernel_mesh, True, kernels=1)
+    want = os.path.join(os.path.dirname(__file__), "testdata",
+                        "jacobi4096x1.v5e.instructions.txt")
+    with open(want) as f:
+        assert stripped(hlo) == f.read().splitlines()
 
 
 def test_layer_scopes_change_no_instruction(kernel_mesh, monkeypatch):

@@ -17,10 +17,11 @@ fused into a single int32 packet (:func:`repro.core.am.pack_packet`) so
 a whole AM crosses a link in ONE ``ppermute`` — the wire shape of the
 paper's GAScore, which parses a single AXIS stream, never two.
 
-Kernels on one device: a pattern's pairs whose two kernels share a
-device take the LOCAL path, a move between the device's slots that
-issues no collective (:func:`repro.core.gascore.deliver_local`); pairs
-across devices keep the ``ppermute``; a mixed pattern does both.
+Kernels on one device: every op is written once, for a lone kernel;
+co-residence comes in only through :meth:`ShoalContext.kernel_map` and
+the slot primitives' batching rules.  Pairs whose kernels share a
+device take the LOCAL path, a move between slots with no collective
+(:func:`repro.core.gascore.deliver_local`); the others a ``ppermute``.
 
 Message-size segmentation: AMs whose payload exceeds the transport's
 ``max_packet_words`` are transparently split into sequence-numbered
@@ -108,17 +109,16 @@ def _device_rounds(ctx: ShoalContext, remote: Pattern):
 
 def _route(ctx: ShoalContext, pattern: Pattern, x: jnp.ndarray):
     """Move ``x`` along ``pattern``: what each destination receives from
-    its source, zeros elsewhere.  With one kernel per device that is one
-    ``ppermute``.  With several, pairs on one device move between slots
-    (``local``), and pairs across devices go by rounds: each packet is
-    first moved into its destination's slot on its own device, then the
-    device's slot stack crosses the link in one ``ppermute`` (``wire``).
+    its source, zeros elsewhere.  Pairs on one device move between slots
+    (``local``), and pairs across devices go by rounds of one
+    ``ppermute`` each (``wire``).  In a round each packet is first moved
+    into its destination's slot on its own device, so that the device's
+    slot stack crosses the link at once; that staging is skipped where
+    every kernel of a sending device already sends to its own slot, as
+    with one kernel per device.
     """
-    if ctx.kernels_per_device == 1:
-        with _lint.layer("wire"):
-            return lax.ppermute(x, ctx.axes, pattern)
-    local, remote = split_local(pattern, ctx.kernels_per_device)
     kpd, me = ctx.kernels_per_device, ctx.my_id()
+    local, remote = split_local(pattern, kpd)
     out = None
     if local:
         with _lint.layer("local"):
@@ -129,8 +129,10 @@ def _route(ctx: ShoalContext, pattern: Pattern, x: jnp.ndarray):
         for s, d in pairs:
             stage[s - s % kpd + d % kpd] = s % kpd
             dst[d] = True
-        with _lint.layer("local"):
-            staged = from_slot(x, jnp.asarray(stage)[me])
+        staged = x
+        if any(stage[a * kpd + j] != j for a, _ in perm for j in range(kpd)):
+            with _lint.layer("local"):
+                staged = from_slot(x, jnp.asarray(stage)[me])
         with _lint.layer("wire"):
             moved = lax.ppermute(staged, ctx.axes, perm)
         out = moved if out is None else jnp.where(jnp.asarray(dst)[me],
@@ -159,10 +161,9 @@ def _exchange(ctx: ShoalContext, pattern: Pattern, hdr: jnp.ndarray,
     Returns ``(hdr, payload)`` — plus ``extra`` in the middle when an
     extra section was given.  Patterns whose pairs all stay on one
     device issue no collective, mirroring libGalapagos' internal
-    routing for same-node kernels: with one kernel per device those are
-    self-puts (src == dst) and return the packet as it is; with several
-    kernels per device each section moves between the device's slots
-    unpacked (:func:`repro.core.gascore.deliver_local`).  A mixed
+    routing for same-node kernels: each section moves between the
+    device's slots unpacked (:func:`repro.core.gascore.deliver_local`;
+    with one kernel per device those pairs are self-puts).  A mixed
     pattern ships one fused packet over both paths (:func:`_route`).
     Non-32-bit payloads cannot bitcast onto the int32 wire and fall back
     to split sections.
@@ -170,8 +171,6 @@ def _exchange(ctx: ShoalContext, pattern: Pattern, hdr: jnp.ndarray,
     local, remote = split_local(pattern, ctx.kernels_per_device)
     _carried(ctx, local, remote, hdr, extra, payload)
     if not remote:
-        if ctx.kernels_per_device == 1:
-            return (hdr, extra, payload) if extra is not None else (hdr, payload)
         with _lint.layer("local"):
             hdr_r, extra_r, pay_r = (
                 None if a is None else gc.deliver_local(ctx, local, a)
@@ -338,12 +337,13 @@ def _require_lossless(op: str, ctx: ShoalContext) -> None:
             "lossless transport or route this op over put_long")
 
 
-def _lossy_recv_probs(ctx: ShoalContext, pattern: Pattern):
+def _lossy_recv_probs(ctx: ShoalContext, remote: Pattern):
     """Per-receiver (drop, dup, corrupt) scalars for one traversal of
-    ``pattern``: each receiver's incoming link is classified statically
-    (LOCAL/ICI links stay lossless even inside a lossy collective)."""
+    the pairs across devices: each receiver's incoming link is
+    classified statically (LOCAL/ICI links stay lossless even inside a
+    lossy collective).  Pairs on one device cross no link: lossless."""
     tbl = np.zeros((ctx.num_kernels, 3), np.float32)
-    for s, d in pattern:
+    for s, d in remote:
         tbl[d] = ctx.transport.probs_for(s, d)
     row = jnp.asarray(tbl)[ctx.my_id()]
     return row[0], row[1], row[2]
@@ -367,12 +367,9 @@ def _lossy_exchange(ctx: ShoalContext, state: PgasState, pattern: Pattern,
         pkt = am.seal_packet(pkt)
     local, remote = split_local(pattern, ctx.kernels_per_device)
     _carried(ctx, local, remote, pkt)
-    if remote or ctx.kernels_per_device > 1:
-        pkt_r = _route(ctx, pattern, pkt)
-    else:
-        pkt_r = pkt
+    pkt_r = _route(ctx, pattern, pkt)
     # the fault emulator stands in for the link: no layer of its own
-    drop, dup, corrupt = _lossy_recv_probs(ctx, pattern)
+    drop, dup, corrupt = _lossy_recv_probs(ctx, remote)
     key = flt.fault_key(ctx.transport.faults, ctx.my_id(), token, epoch,
                         rnd, direction)
     delivered = flt.deliver(pkt_r, key, drop, dup, corrupt)
@@ -1226,9 +1223,8 @@ def barrier(ctx: ShoalContext, state: PgasState) -> PgasState:
     tag = _lint.emit("barrier", [])
     with _lint.scope(tag), _lint.layer("sync"):
         one = jnp.ones((), jnp.int32)
-        if ctx.kernels_per_device > 1:
-            with _lint.layer("local"):
-                one = slots_total(one)
+        with _lint.layer("local"):
+            one = slots_total(one)
         with _lint.layer("wire"):
             arrived = lax.psum(one, ctx.axes)
         epoch = state.barrier_epoch + (arrived // arrived)  # data-dependent

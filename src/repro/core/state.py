@@ -259,14 +259,13 @@ class ShoalContext:
         return self.num_devices * self.kernels_per_device
 
     def my_id(self):
-        """Kernel ID of the executing kernel (inside :meth:`kernel_map`)."""
-        if self.kernels_per_device == 1:
-            return lax.axis_index(self.axes)
-        if not _SLOTS:
+        """Kernel ID of the executing kernel (inside :meth:`kernel_map`):
+        ``device * kernels_per_device + slot``; a lone kernel's slot is 0."""
+        if not _SLOTS and self.kernels_per_device > 1:
             raise RuntimeError("my_id() of kernels that share a device is "
                                "defined inside ShoalContext.kernel_map")
-        return lax.axis_index(self.axes) * self.kernels_per_device \
-            + _SLOTS[-1]
+        slot = _SLOTS[-1] if _SLOTS else 0
+        return lax.axis_index(self.axes) * self.kernels_per_device + slot
 
     def make_state(self, dtype=jnp.float32) -> PgasState:
         return PgasState.make(self.segment_words, dtype)
@@ -293,7 +292,7 @@ class ShoalContext:
         under a plain ``shard_map`` with one kernel per device.  Where
         devices hold several kernels, every argument and result is split
         over the kernels, and ``fn`` runs once per slot under a
-        ``vmap``."""
+        ``vmap``: the one place that chooses a path by the kernel count."""
         if self.kernels_per_device == 1:
             return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                              out_specs=out_specs, **shard_map_kwargs)
@@ -328,8 +327,9 @@ class ShoalContext:
 
 
 # -- the slot primitives ------------------------------------------------------
-# Outside the slot ``vmap`` a kernel is alone on its device; under it,
-# their batching rules see every kernel of the device at once.
+# The only code that sees kernels share a device.  Outside the slot
+# ``vmap`` a kernel is alone on its device (the unbatched rules); under
+# it, their batching rules see every kernel of the device at once.
 
 @custom_vmap
 def from_slot(x, src):

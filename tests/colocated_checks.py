@@ -11,8 +11,13 @@ others.  The checks:
   segment, credits, ledger and counters as the same program does with
   one kernel per device, on layouts where the patterns are all LOCAL
   (8 kernels on 1 device) and mixed (2 devices of 4);
+* the same for the mailbox flush of a mixed Long and Short stack, the
+  strided put (aliasing and not) and the vectored put;
 * a pattern with LOCAL and ICI pairs at once, whose devices exchange
-  with several devices (4 devices of 2);
+  with several devices (4 devices of 2), and one whose ICI pairs keep
+  their slots, so that no in-device staging is built;
+* the reliable put on a lossy transport whose faults fall on ICI links
+  only (4 devices of 2);
 * the collective budgets of the Jacobi programs, the colocated one
   (no collective-permute) and the two with one kernel per device.
 """
@@ -211,6 +216,80 @@ def barrier_short_and_medium():
         np.testing.assert_array_equal(st.segment[k, :30], (k + 1) % K + 1)
 
 
+@check
+def mailbox_flush_mixed_stack():
+    """Long writes, a Long add and Short signals in one flush: the
+    mixed-class ingress of the mailbox."""
+    from repro.actors import Mailbox
+
+    def prog(ctx, st):
+        mb = Mailbox(ctx, RING, msg_words=4, watermark=64, token=5)
+        f = scale(ctx)
+        for i in range(5):
+            st = mb.send(st, f * (jnp.arange(4.0) + 1) + 10 * i,
+                         dst_addr=8 * i)
+        st = mb.send(st, jnp.full((4,), 0.5), dst_addr=0, handler=hd.H_ADD)
+        st = mb.send_signal(st, arg=3, token=7)
+        st = mb.send_signal(st, arg=2, token=7)
+        st = mb.flush(st)
+        return ops.wait_replies(ctx, st, token=5, n=1)
+
+    st = same_state(prog)
+    for k in range(K):
+        src = (k - 1) % K
+        for i in range(5):
+            want = (src + 1) * (np.arange(4.0) + 1) + 10 * i + (i == 0) * 0.5
+            np.testing.assert_array_equal(st.segment[k, 8 * i:8 * i + 4],
+                                          want)
+    assert (st.credits[:, 7] == 5).all() and (st.credits[:, 5] == 0).all()
+
+
+@check
+def put_long_strided():
+    """A stride that does not alias (segmented at block granularity)
+    and one that does (the ordered, last-writer-wins ingress)."""
+    def prog(ctx, st):
+        f = scale(ctx)
+        st = ops.put_long_strided(ctx, st, jnp.arange(24.0) * f, RING,
+                                  dst_addr=4, stride=10, blk_words=4,
+                                  nblocks=6, token=1)
+        st = ops.put_long_strided(ctx, st, jnp.arange(16.0) * f + 100, BACK,
+                                  dst_addr=70, stride=2, blk_words=4,
+                                  nblocks=4, token=2)
+        st = ops.wait_replies(ctx, st, token=1, n=1)
+        return ops.wait_replies(ctx, st, token=2, n=1)
+
+    st = same_state(prog)
+    for k in range(K):
+        f = (k - 1) % K + 1
+        for b in range(6):
+            np.testing.assert_array_equal(
+                st.segment[k, 4 + 10 * b:8 + 10 * b],
+                np.arange(4 * b, 4 * b + 4) * f)
+        f = (k + 1) % K + 1
+        want = np.zeros(10)
+        for b in range(4):
+            want[2 * b:2 * b + 4] = np.arange(4 * b, 4 * b + 4) * f + 100
+        np.testing.assert_array_equal(st.segment[k, 70:80], want)
+
+
+@check
+def put_long_vectored():
+    def prog(ctx, st):
+        f = scale(ctx)
+        blocks = [jnp.arange(3.0) * f, jnp.full(5, -f), jnp.ones(2) * f]
+        st = ops.put_long_vectored(ctx, st, blocks, RING,
+                                   dst_addrs=[30, 10, 60], token=1)
+        return ops.wait_replies(ctx, st, token=1, n=1)
+
+    st = same_state(prog)
+    for k in range(K):
+        f = (k - 1) % K + 1
+        np.testing.assert_array_equal(st.segment[k, 30:33], np.arange(3) * f)
+        np.testing.assert_array_equal(st.segment[k, 10:15], -f)
+        np.testing.assert_array_equal(st.segment[k, 60:62], f)
+
+
 # -- LOCAL and ICI pairs in one pattern -------------------------------------------
 
 @check
@@ -254,6 +333,72 @@ def mixed_pattern():
         assert rec.links["LOCAL"]["packets"] == 6 * local, rec.links
         assert rec.links.get("ICI", {}).get("packets", 0) == \
             6 * (8 - local), rec.links
+
+
+@check
+def in_slot_pattern():
+    """On 4 devices of 2, pairs that keep their slot (kernel 0 to 2, 1
+    to 3) cross the link as the device's slot stack as it is: no
+    in-device staging (no ``local`` instruction) is built.  Pairs that
+    swap slots need it."""
+    from repro.launch.hlo_analysis import op_layers
+
+    def prog_for(pattern):
+        def prog(ctx, st):
+            st = ops.put_long(ctx, st, jnp.arange(20.0) * scale(ctx),
+                              pattern, dst_addr=4, token=1)
+            sender = (ctx.my_id() < 2).astype(jnp.int32)
+            return ops.wait_replies(ctx, st, token=1, n=sender)
+        return prog
+
+    for pattern in ([(0, 2), (1, 3)], [(0, 3), (1, 2)]):
+        prog = prog_for(pattern)
+        st = same_state(prog, layouts=("1x8", "2x4", "4x2"))
+        for s, d in pattern:
+            np.testing.assert_array_equal(st.segment[d, 4:24],
+                                          np.arange(20) * (s + 1))
+        ctx = context("4x2")
+        gas = GlobalAddressSpace(ctx)
+        hlo = jax.jit(gas.spmd(lambda s: prog(ctx, s))).lower(
+            gas.make_global_state()).compile().as_text()
+        layers = set(op_layers(hlo).values())
+        assert "wire" in layers
+        assert ("local" in layers) == (pattern[0] == (0, 3)), (pattern,
+                                                               layers)
+
+
+@check
+def reliable_put_long_ici_faults():
+    """The reliable put over the ring on 4 devices of 2 kernels, with
+    faults on ICI links only: every segment lands as on a lossless
+    link, the credits balance, no error bit is set, and only the pairs
+    that cross devices retransmit."""
+    from repro.core.faults import FaultModel
+    from repro.runtime import LossyTransport
+    from repro.runtime.transport import LinkClass
+
+    def prog(ctx, st):
+        pay = (jnp.arange(16.0) + 1) * scale(ctx)
+        st = ops.put_long(ctx, st, pay, RING, dst_addr=10, token=1)
+        return ops.wait_replies(ctx, st, token=1, n=1, timeout=True)
+
+    # 4 payload words a packet: the put is 4 segments
+    want = run("8x1", prog, transport=dataclasses.replace(
+        TCP, max_packet_bytes=16)).segment
+    retried = np.zeros(K, bool)
+    for seed in (7, 11, 19):
+        lossy = LossyTransport(faults=FaultModel(drop=0.1, seed=seed),
+                               max_packet_bytes=16,
+                               lossy_links=(LinkClass.ICI,),
+                               link_of=lambda s, d: LinkClass.ICI)
+        st = run("4x2", prog, transport=lossy)
+        np.testing.assert_array_equal(st.segment, want)
+        assert (st.credits == 0).all() and (st.dedup_seen == 0).all()
+        assert (st.dedup_epoch[:, 1] == 1).all()
+        assert not st.error.any(), st.error
+        retried |= st.retransmits > 0
+    # kernel k sends to k + 1: odd kernels cross a device boundary
+    assert retried[1::2].any() and not retried[0::2].any(), retried
 
 
 # -- collective budgets -----------------------------------------------------------

@@ -1,7 +1,8 @@
 """Several Shoal kernels on one device (the paper's several kernels on
 one node): the Jacobi app against the plain reference, the AM ops
-between co-resident kernels against one kernel per device, a pattern
-with LOCAL and ICI pairs at once, and the collective budgets.  The
+between co-resident kernels against one kernel per device, patterns
+with LOCAL and ICI pairs at once, the reliable put under ICI faults,
+and the collective budgets.  The
 checks run once, in a subprocess with 8 host devices
 (tests/colocated_checks.py); each is a case here."""
 

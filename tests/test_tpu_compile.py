@@ -146,6 +146,8 @@ def test_jacobi_app_4_kernels_compiles(kernel_mesh, monkeypatch, use_pallas):
     permutes = [k for k, (op, _) in instrs.items()
                 if op.startswith("collective-permute")]
     assert permutes and all(layers[k] == "wire" for k in permutes)
+    # one kernel per device: no op builds an in-device move
+    assert "local" not in set(layers.values())
     _assert_ingress_has_no_loop(layers, instrs)
     if use_pallas:
         calls = [(k, rest) for k, (op, rest) in instrs.items()

@@ -15,13 +15,13 @@ of the module the window ran turns the trace into layers:
   :data:`UNMAPPED_LIMIT` of a chip's busy time, nothing is read.
 
 The map is the executed module's: the harness frees the program after
-the window, so :func:`op_layers` has the app build its program again
-from the cell's configuration and compile it for the window's devices
-and shapes (a hit in the compile cache).  Each app that has layer
-readers brings that rebuild as ``apps/<app>_module.py``, found by name
-as apps and metrics are; this module names no app.  A program without
-the map (no ``op_layers``, or no layer scopes) gives no reading, nor
-does an app without a rebuild.
+the window, so :func:`module` has the app build its program again from
+the cell's configuration and compile it for the window's devices and
+shapes (a hit in the compile cache), and :func:`op_layers` maps it.
+Each app that has layer readers brings that rebuild as
+``apps/<app>_module.py``, found by name as apps and metrics are; this
+module names no app.  A program without the map (no ``op_layers``, or
+no layer scopes) gives no reading, nor does an app without a rebuild.
 """
 
 from __future__ import annotations
@@ -98,6 +98,14 @@ def module_text(config: dict, traffic: dict) -> str | None:
     return mod.module_text(config, traffic)
 
 
+def module(run) -> str | None:
+    """The compiled text of the module the run's window executed, once
+    per run (kept on the run); None for an app without a rebuild."""
+    if "_module_text" not in vars(run):
+        run._module_text = module_text(run.cell.config, run.cell.traffic)
+    return run._module_text
+
+
 def op_layers(run) -> dict | None:
     """The layer map of the module the run's window executed, once per
     run (kept on the run); None where the program under test has no
@@ -106,8 +114,7 @@ def op_layers(run) -> dict | None:
         from repro.launch import hlo_analysis
 
         parse = getattr(hlo_analysis, "op_layers", None)
-        text = (module_text(run.cell.config, run.cell.traffic)
-                if parse is not None else None)
+        text = module(run) if parse is not None else None
         run._layer_map = parse(text) if text is not None else None
     return run._layer_map
 

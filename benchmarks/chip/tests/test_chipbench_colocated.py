@@ -6,7 +6,6 @@ refused at set-up, the layer map rebuilt for it, and the trace of 8
 kernels on one TPU v5e read by layer."""
 
 import dataclasses
-import gzip
 import random
 
 import pytest
@@ -17,7 +16,6 @@ import layers
 import trace_reduce as tr
 
 NAME = "jacobi-4096x8.1chip"
-TESTDATA = h.BENCH_DIR / "testdata"
 
 
 def small_cell() -> harness.Cell:
@@ -36,7 +34,8 @@ def test_cell_entries():
     per_layer = {m["name"] for m in cell.per_layer}
     assert per_layer == {"stencil_kernel_ms", "stencil_ms", "am_ingress_ms",
                          "am_egress_ms", "am_local_ms",
-                         "device_idle_pct.jacobi"}
+                         "device_idle_pct.jacobi", "jacobi_band_gb_per_s",
+                         "carry_copy_ms"}
     assert {m["name"] for m in cell.end_to_end} == {"jacobi_iter_ms",
                                                     "setup_s"}
 
@@ -106,19 +105,9 @@ def recorded_run():
     """``jacobi512x8`` (512x512, 8 kernels on one chip, 8 iterations a
     call, 3 calls; ``record_colocated_trace.py``) as the harness hands
     it to the readers, with the map of the module that ran."""
-    from repro.launch.hlo_analysis import op_layers
+    from test_chipbench_layers import recorded_run as recorded
 
-    run = h.harness.Run(cell=None, seed=0,
-                        peaks=h.harness.pk.peaks_for("TPU v5 lite"))
-    run.calls = [(0.0, 1.0)] * 3
-    run.work_per_call = {"iters": 8}
-    run.trace = tr.load(gzip.open(TESTDATA / "jacobi512x8.xplane.pb.gz")
-                        .read())
-    run.trace_window = tr.window(run.trace)
-    run.details = {"n": 512, "kernels": 8}
-    run._layer_map = op_layers(gzip.open(TESTDATA / "jacobi512x8.hlo.txt.gz",
-                                         "rt").read())
-    return run
+    return recorded("jacobi512x8")
 
 
 def test_recorded_colocated_trace_by_layer():
@@ -155,5 +144,5 @@ def test_no_local_reading_without_the_local_scope():
     ``am_local_ms`` reads nothing."""
     from test_chipbench_layers import recorded_run as four_kernels
 
-    run = four_kernels("jacobi512x4", kernels=4)
+    run = four_kernels("jacobi512x4")
     assert h.harness.load_reader("am_local_ms").read(run) is None

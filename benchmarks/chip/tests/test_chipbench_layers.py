@@ -1,9 +1,11 @@
 """Device time charged to the program's layer scopes (``layers.py``), on
-hand-made events, on the two traces recorded on a TPU v5e, and the layer
-map rebuilt for a cell against the module its window runs."""
+hand-made events, on the traces recorded on a TPU v5e, and the layer
+map rebuilt for a cell against the module its window runs; the device
+layer's band rate and the copies of the solve loop's band carry."""
 
 import gzip
 import random
+import re
 
 import pytest
 
@@ -72,7 +74,9 @@ def test_layer_reading_needs_a_map_that_fits():
 JACOBI512_READINGS = {
     "stencil_kernel_ms": 0.0009916666666666665,
     "jacobi_collective_ms": None,
-    "jacobi_roofline": 98.50452597134652,
+    # the share of HBM's 819 GB/s it read as ``jacobi_roofline``
+    "jacobi_band_gb_per_s": pytest.approx(98.50452597134652 / 100 * 819,
+                                          rel=1e-9),
     "device_idle_pct.jacobi": 98.66436882499652,
 }
 JACOBI512_OPS = [
@@ -102,33 +106,43 @@ JACOBI512_GAPS = [
 LAYER_READERS = ("stencil_ms", "am_ingress_ms", "am_egress_ms")
 
 
-def recorded_run(name, kernels):
+# each recorded trace, and the cell whose layout it has at 512x512
+RECORDED = {"jacobi512": "jacobi-4096.1chip",
+            "jacobi512x4": "jacobi-4096.4chip",
+            "jacobi512x8": "jacobi-4096x8.1chip"}
+
+
+def recorded_run(name):
     """A run of a recorded trace (3 calls of 8 iterations, 512x512) as
-    the harness hands it to the readers, with the map of the module
-    recorded beside it (none for ``jacobi512``)."""
+    the harness hands it to the readers, in the cell whose layout it has,
+    with the module recorded beside it and its map (none for
+    ``jacobi512``)."""
     from repro.launch.hlo_analysis import op_layers
 
-    run = h.harness.Run(cell=None, seed=0,
+    cell = h.harness.find_cell(RECORDED[name])
+    run = h.harness.Run(cell=cell, seed=0,
                         peaks=h.harness.pk.peaks_for("TPU v5 lite"))
     run.calls = [(0.0, 1.0)] * 3
     run.work_per_call = {"iters": 8}
     run.trace = tr.load(gzip.open(TESTDATA / f"{name}.xplane.pb.gz").read())
     run.trace_window = tr.window(run.trace)
-    run.details = {"n": 512, "kernels": kernels}
+    run.details = {"n": 512, "kernels": cell.traffic["kernels"]}
     hlo = TESTDATA / f"{name}.hlo.txt.gz"
-    run._layer_map = (op_layers(gzip.open(hlo, "rt").read())
-                      if hlo.is_file() else None)
+    run._module_text = (gzip.open(hlo, "rt").read() if hlo.is_file()
+                        else None)
+    run._layer_map = (op_layers(run._module_text)
+                      if run._module_text is not None else None)
     return run
 
 
 def test_readers_read_the_committed_trace_as_before():
-    run = recorded_run("jacobi512", kernels=1)
+    run = recorded_run("jacobi512")
     for name, value in JACOBI512_READINGS.items():
         assert h.harness.load_reader(name).read(run) == value, name
     b = tr.breakdown(run.trace, *run.trace_window)
     assert b == {"device_ops": JACOBI512_OPS, "idle_gaps": JACOBI512_GAPS}
     # no module text was recorded with it: the layer readers read nothing
-    for name in LAYER_READERS:
+    for name in LAYER_READERS + ("carry_copy_ms",):
         assert h.harness.load_reader(name).read(run) is None
     assert "layer_ms_per_iter" not in run.details
 
@@ -149,7 +163,7 @@ def test_recorded_four_kernel_trace_by_layer():
     (which the compiler names ``psum_invariant.N``) included.  The module
     text was recorded when the ``compute`` scope was named ``stencil``;
     its 25 ``op_name`` entries were renamed to match, metadata only."""
-    run = recorded_run("jacobi512x4", kernels=4)
+    run = recorded_run("jacobi512x4")
     t, (lo, hi) = run.trace, run.trace_window
     assert list(t.devices) == [0, 1, 2, 3]
     per = layers.self_ns_by_layer(t, lo, hi, run._layer_map)
@@ -216,7 +230,7 @@ def test_no_map_without_the_programs_parser(monkeypatch):
     layer scopes) gives no map and no reading."""
     from repro.launch import hlo_analysis
 
-    run = recorded_run("jacobi512x4", kernels=4)
+    run = recorded_run("jacobi512x4")
     monkeypatch.delattr(hlo_analysis, "op_layers")
     del run._layer_map
     run.cell = h.small_cell("jacobi-4096.4chip")
@@ -231,12 +245,121 @@ def test_no_map_for_an_app_without_a_rebuild():
     readers read nothing."""
     import dataclasses
 
-    run = recorded_run("jacobi512x4", kernels=4)
-    del run._layer_map
+    run = recorded_run("jacobi512x4")
+    del run._layer_map, run._module_text
     cell = h.small_cell("jacobi-4096.4chip")
     run.cell = dataclasses.replace(cell, config={**cell.config,
                                                  "app": "no-such-app"})
     assert layers.module_text(run.cell.config, run.cell.traffic) is None
-    for name in LAYER_READERS:
+    for name in LAYER_READERS + ("carry_copy_ms",):
         assert h.harness.load_reader(name).read(run) is None
     assert "layer_ms_per_iter" not in run.details
+
+
+# -- the device layer: the band's rate and the copies of its carry ------------
+
+def test_band_bytes_per_chip():
+    """An iteration reads and writes the chip's rows once, however many
+    kernels share the chip: the 64-MiB grid twice on one kernel and on 8
+    kernels of one chip, a quarter of it twice on each of 4 chips."""
+    reader = h.harness.load_reader("jacobi_band_gb_per_s")
+    moved = {}
+    for name in RECORDED.values():
+        cell = h.harness.find_cell(name)
+        moved[name] = reader.bytes_per_chip(cell.config["n"], cell.chips,
+                                            cell.config["dtype"])
+    assert moved == {"jacobi-4096.1chip": 2 * 4096 * 4096 * 4,
+                     "jacobi-4096.4chip": 2 * 1024 * 4096 * 4,
+                     "jacobi-4096x8.1chip": 2 * 4096 * 4096 * 4}
+
+
+def test_band_rate_counts_rows_per_chip():
+    """On 8 kernels of one chip the rate counts the chip's 512 rows, not
+    one kernel's 64; on 4 chips each chip's 128 rows, mean over chips."""
+    reader = h.harness.load_reader("jacobi_band_gb_per_s")
+    for name, rows in (("jacobi512x8", 512), ("jacobi512x4", 128)):
+        run = recorded_run(name)
+        busy = tr.busy_ns(run.trace, *run.trace_window)
+        want = tr.mean({d: 2 * rows * 512 * 4 * 24 / b
+                        for d, b in busy.items()})
+        assert reader.read(run) == pytest.approx(want, rel=1e-12), name
+    run.trace = None
+    assert reader.read(run) is None
+
+
+BAND_LOOP = """HloModule solve
+
+%copy_computation (param_0: f32[8,128]) -> f32[1024] {
+  %param_0 = f32[8,128]{1,0} parameter(0)
+  %copy.1 = f32[8,128]{0,1} copy(%param_0)
+  ROOT %bitcast.1 = f32[1024]{0} bitcast(%copy.1)
+}
+
+%body (p: (s32[], f32[8,128], f32[8])) -> (s32[], f32[8,128], f32[8]) {
+  %p = (s32[], f32[8,128]{1,0}, f32[8]{0}) parameter(0)
+  %gte.1 = f32[8,128]{1,0} get-tuple-element(%p), index=1
+  %gte.2 = f32[8]{0} get-tuple-element(%p), index=2
+  %copy.2 = f32[8,128]{1,0:T(8,128)S(1)} copy(%gte.1)
+  %copy_bitcast_fusion.3 = f32[1024]{0} fusion(%copy.2), kind=kLoop, calls=%copy_computation
+  %copy-start.4 = (f32[8,128]{1,0}, f32[8,128]{1,0:S(1)}, u32[]{:S(2)}) copy-start(%gte.1)
+  %copy-done.4 = f32[8,128]{1,0} copy-done(%copy-start.4)
+  %copy.5 = f32[8]{0} copy(%gte.2)
+  %copy.6 = s32[1024]{0} copy(%bitcast.9)
+  ROOT %tuple.1 = (s32[], f32[8,128]{1,0}, f32[8]{0}) tuple(%c, %copy-done.4, %copy.5)
+}
+
+ENTRY %main (a: f32[8,128]) -> f32[8,128] {
+  %a = f32[8,128]{1,0} parameter(0)
+  %copy.7 = f32[8,128]{1,0:S(1)} copy(%a)
+  %while.1 = (s32[]{:T(128)}, f32[8,128]{1,0:T(8,128)S(1)}, f32[8]{0}) while(%tuple.0), condition=%cond, body=%body
+  ROOT %copy.8 = f32[8,128]{1,0} copy(%gte.9)
+}
+"""
+
+
+def test_band_copies_are_the_solve_loops():
+    """The copies of the band the solve loop carries, in the loop's body:
+    a copy, a copy fusion of another shape with as many elements, an
+    asynchronous copy's two halves; not a copy of a smaller array or of
+    another type, and not the band's copies before and after the loop,
+    once a solve."""
+    reader = h.harness.load_reader("carry_copy_ms")
+    assert reader.band_copies(BAND_LOOP) == {
+        "copy.2", "copy_bitcast_fusion.3", "copy-start.4", "copy-done.4"}
+    # a loop body that keeps the band in place copies none of it; a
+    # module with no loop carries no band
+    assert reader.band_copies(re.sub(r"\n  %copy[^\n]*", "",
+                                     BAND_LOOP)) == set()
+    assert reader.band_copies(BAND_LOOP.replace(" while(", " call(")
+                              .replace("body=", "to_apply=")) == set()
+
+
+@pytest.mark.parametrize("name,copies", [
+    ("jacobi512x4", {"copy.152"}),
+    ("jacobi512x8", {"copy.357", "copy.217"}),
+])
+def test_carry_copy_on_recorded_traces(name, copies):
+    """In the modules recorded on a TPU v5e the solve loop copies its
+    band each iteration (in ``S(1)``; on 8 kernels once more to another
+    layout), and the copies took device time."""
+    reader = h.harness.load_reader("carry_copy_ms")
+    run = recorded_run(name)
+    assert reader.band_copies(run._module_text) == copies
+    assert reader.read(run) > 0
+
+
+def test_carry_copy_reads_zero_without_a_band_copy():
+    """A trace whose loop ran no copy of the band reads 0.0: the stencil,
+    a halo-row copy in the loop and the band's copies before and after
+    the loop take time, none of it the carry's.  No trace, no reading."""
+    reader = h.harness.load_reader("carry_copy_ms")
+    run = recorded_run("jacobi512x8")
+    run.trace = tr.Trace({0: [ev("jacobi_step_pallas.17", 0, 50),
+                              ev("copy.223", 50, 60),
+                              ev("copy-done.5", 60, 80),
+                              ev("copy-done.4", 80, 90)]},
+                         [ev("bench.window", 0, 100)])
+    run.trace_window = (0.0, 100.0)
+    assert reader.read(run) == 0.0
+    run.trace = None
+    assert reader.read(run) is None

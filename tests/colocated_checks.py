@@ -5,8 +5,9 @@ Each check runs on its own and prints one JSON line, ``{"check": name,
 "ok": bool, "error": text}``, so that one failure does not hide the
 others.  The checks:
 
-* the Jacobi app on 8 kernels over 1 and 2 devices and on 4 kernels
-  over 1, at 1 and 4 iterations, equals the plain reference;
+* the Jacobi app on 8 kernels over 1 and 2 devices, on 4 kernels over
+  1 and 4 and on 1 kernel, at 1, 3 and 4 iterations, equals the plain
+  reference and consumes every reply credit;
 * the AM ops between kernels that share a device leave every kernel's
   segment, credits, ledger and counters as the same program does with
   one kernel per device, on layouts where the patterns are all LOCAL
@@ -102,8 +103,16 @@ def jacobi_case(kernels, chips, iters):
         (64, 64)).astype(np.float32)
     app = JacobiApp(n=64, kernels=kernels, iters=iters, transport=TINY_TCP,
                     use_pallas=True, interpret=True, chips=chips)
-    err = np.abs(app.run(grid) - jacobi_reference(grid, iters)).max()
+    gas = GlobalAddressSpace(app.ctx)
+    st, out = app.build()(gas.make_global_state(),
+                          jnp.asarray(grid.reshape(kernels, app.rows, 64)))
+    st = jax.tree.map(np.asarray, jax.device_get(st))
+    err = np.abs(np.asarray(out).reshape(64, 64)
+                 - jacobi_reference(grid, iters)).max()
     assert err <= 1e-5, err
+    # after the drain every halo's reply credit is consumed
+    assert not st.error.any(), st.error
+    assert (st.credits == 0).all() and (st.deferred_acks == 0).all(), st
     links = app.links_per_iteration()
     # a 64-word halo row is 4 packets at a 16-word MTU
     boundaries = 2 * (kernels - 1)
@@ -113,8 +122,9 @@ def jacobi_case(kernels, chips, iters):
     assert links.get("ICI", {}).get("packets", 0) == 4 * crossing, links
 
 
-for _k, _c in ((8, 1), (8, 2), (4, 1)):
-    for _it in (1, 4):
+# odd and even iteration counts: the solve loop runs two a trip
+for _k, _c in ((8, 1), (8, 2), (4, 1), (4, 4), (1, 1)):
+    for _it in (1, 3, 4):
         def _case(k=_k, c=_c, it=_it):
             jacobi_case(k, c, it)
         CHECKS[f"jacobi_{_k}k_{_c}dev_{_it}it"] = _case

@@ -86,10 +86,12 @@ _SHAPE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = [a-z]\w*\[([\d,]*)\]",
 
 
 def _jacobi_app_hlo(monkeypatch, kernel_mesh, use_pallas, kernels=4,
-                    chips=None):
+                    chips=None, iters=4):
+    """The compiled Jacobi app at grid 4096; ``iters=4`` keeps a solve
+    loop of two trips (a single trip of two iterations is unrolled)."""
     import repro.apps.jacobi as jacobi_app
     monkeypatch.setattr(jacobi_app, "make_cpu_mesh", kernel_mesh)
-    app = jacobi_app.JacobiApp(n=4096, kernels=kernels, iters=2,
+    app = jacobi_app.JacobiApp(n=4096, kernels=kernels, iters=iters,
                                use_pallas=use_pallas, chips=chips)
     blocks = jax.ShapeDtypeStruct(
         (kernels, app.rows, app.n), jnp.float32,
@@ -99,9 +101,11 @@ def _jacobi_app_hlo(monkeypatch, kernel_mesh, use_pallas, kernels=4,
 
 
 def _assert_stencil_reads_band_once(hlo, rows, n):
-    """The stencil's call has one band-sized operand, the band the solve
-    loop carries (through copies alone), and no band-sized pad or fusion
-    of layer ``compute`` is built beside it."""
+    """Each stencil call has one band-sized operand (its bitcasts are the
+    same buffer): the band the solve loop carries, or the other call's
+    result in a trip of two iterations, reached through copies alone;
+    and no band-sized pad or fusion of layer ``compute`` is built beside
+    it."""
     from repro.launch.hlo_analysis import op_layers
 
     layers, instrs = op_layers(hlo), _instructions(hlo)
@@ -109,17 +113,24 @@ def _assert_stencil_reads_band_once(hlo, rows, n):
             for k, s in _SHAPE.findall(hlo)}
     band = lambda k: (len(dims.get(k, ())) == 2  # noqa: E731
                       and dims[k][0] >= rows - 1 and dims[k][1] == n)
-    calls = [rest for op, rest in instrs.values()
-             if op == "custom-call" and "tpu_custom_call" in rest]
+
+    def source(a, through):
+        while instrs[a][0] in through:
+            a = re.findall(r"%([\w.\-]+)", instrs[a][1])[0]
+        return a
+
+    calls = {k: rest for k, (op, rest) in instrs.items()
+             if op == "custom-call" and "tpu_custom_call" in rest}
     assert calls
-    for rest in calls:
-        bands = {a for a in re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+    for rest in calls.values():
+        bands = {source(a, ("bitcast",))
+                 for a in re.findall(r"%([\w.\-]+)", rest.split(")")[0])
                  if band(a)}
         assert len(bands) == 1, bands
-        a = bands.pop()
-        while instrs[a][0] in ("copy", "copy-start", "copy-done", "bitcast"):
-            a = re.findall(r"%([\w.\-]+)", instrs[a][1])[0]
-        assert instrs[a][0] == "get-tuple-element", (a, instrs[a])
+        a = source(bands.pop(), ("copy", "copy-start", "copy-done",
+                                 "bitcast"))
+        assert instrs[a][0] == "get-tuple-element" or a in calls, \
+            (a, instrs[a])
     views = [k for k, (op, _) in instrs.items()
              if op in ("pad", "fusion") and layers[k] == "compute" and band(k)]
     assert not views, views
@@ -168,8 +179,8 @@ def test_jacobi_app_8_kernels_on_1_chip_compiles(kernel_mesh, monkeypatch):
     """The paper's 8 kernels on one node, on one chip: no collective is
     left (the halos take the LOCAL path, the barrier's psum over one
     device goes), the in-chip moves carry the ``local`` layer, and the
-    stencil is one ``jacobi_step_pallas`` call over the 8 stacked
-    bands."""
+    stencil is one ``jacobi_step_pallas`` call an iteration, each over
+    the 8 stacked bands."""
     from repro.launch.hlo_analysis import op_layers, parse_collectives
 
     hlo = _jacobi_app_hlo(monkeypatch, kernel_mesh, True, kernels=8,
@@ -179,10 +190,67 @@ def test_jacobi_app_8_kernels_on_1_chip_compiles(kernel_mesh, monkeypatch):
     assert "local" in set(layers.values())
     calls = [k for k, (op, rest) in instrs.items()
              if op == "custom-call" and "tpu_custom_call" in rest]
-    assert len(calls) == 1 and calls[0].startswith("jacobi_step_pallas")
-    assert layers[calls[0]] == "compute"
-    assert re.search(rf"%{re.escape(calls[0])} = f32\[8,512,4096\]", hlo)
+    # the solve loop runs two iterations a trip
+    assert len(calls) == 2
+    for call in calls:
+        assert call.startswith("jacobi_step_pallas")
+        assert layers[call] == "compute"
+        assert re.search(rf"%{re.escape(call)} = f32\[8,512,4096\]", hlo)
     _assert_ingress_has_no_loop(layers, instrs)
+
+
+def _computations(hlo: str) -> tuple[str, dict]:
+    """The entry computation's name and ``{computation: {instruction:
+    (opcode, rest of the line)}}`` of a compiled module."""
+    entry, comps, current = None, {}, None
+    for line in hlo.splitlines():
+        if m := re.match(r"^(ENTRY )?%([\w.\-]+) ", line):
+            current = m.group(2)
+            comps[current] = {}
+            entry = current if m.group(1) else entry
+        elif (m := _INSTR.match(line)) and current is not None:
+            comps[current][m.group(1)] = (m.group(2), m.group(3))
+    return entry, comps
+
+
+@pytest.fixture(scope="module")
+def band_copies():
+    """``carry_copy_ms``'s rule: the copies of the solve loop's band in
+    the loop's body, as the benchmark reads them off the chip's trace."""
+    import importlib.util
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    spec = importlib.util.spec_from_file_location(
+        "carry_copy_ms", os.path.join(bench, "metrics", "carry_copy_ms.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    return reader.band_copies
+
+
+@pytest.mark.parametrize("kernels,chips", [(1, None), (4, None), (8, 1)])
+def test_jacobi_solve_loop_copies_no_band(kernel_mesh, monkeypatch,
+                                          band_copies, kernels, chips):
+    """The solve loop runs two iterations a trip, so the two stencil
+    calls alternate the band between HBM and on-chip memory: its body
+    holds both calls and no copy of the band, on one kernel, on four,
+    and on eight kernels of one chip."""
+    hlo = _jacobi_app_hlo(monkeypatch, kernel_mesh, True, kernels=kernels,
+                          chips=chips, iters=1024)
+    assert band_copies(hlo) == set()
+    entry, comps = _computations(hlo)
+    bodies = {m.group(1) for op, rest in comps[entry].values()
+              if op == "while" and (m := re.search(r"body=%([\w.\-]+)",
+                                                    rest))}
+    calls = {c: [k for k, (op, rest) in instrs.items()
+                 if op == "custom-call" and "tpu_custom_call" in rest]
+             for c, instrs in comps.items()}
+    (body, names), = ((c, ks) for c, ks in calls.items() if ks)
+    assert body in bodies and len(names) == 2, (body, names)
+    assert all(k.startswith("jacobi_step_pallas") for k in names)
 
 
 def test_jacobi_app_1_kernel_module_unchanged(kernel_mesh, monkeypatch):
